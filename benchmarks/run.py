@@ -266,6 +266,7 @@ def kernel_bench() -> None:
     the TPU tiling; see DESIGN.md §3)."""
     import jax
     import jax.numpy as jnp
+    from repro.kernels import default_interpret
     from repro.kernels.imc_mav import ops as mav_ops
     from repro.kernels.imc_mav.ref import imc_mav_ref
     from repro.kernels.int8_matmul.int8_matmul import int8_matmul
@@ -288,7 +289,9 @@ def kernel_bench() -> None:
     xq = jax.random.randint(kxq, (512, 128), -127, 128, jnp.int8)
     wq = jax.random.randint(kwq, (128, 128), -127, 128, jnp.int8)
     bq = jnp.zeros((128,), jnp.int32)
-    us = _time_us(lambda: int8_matmul(xq, wq, bq, shift=7))
+    interpret = default_interpret()
+    us = _time_us(lambda: int8_matmul(xq, wq, bq, shift=7,
+                                      interpret=interpret))
     us_ref = _time_us(jax.jit(lambda: int8_matmul_ref(xq, wq, bq, shift=7)))
     _row("kernel_int8_matmul_512x128x128", f"{us:.0f}",
          f"ref_us={us_ref:.0f}")
@@ -297,7 +300,8 @@ def kernel_bench() -> None:
     wv = jax.random.uniform(kwv, (n,), minval=-1, maxval=1)
     gv = jax.random.normal(kgv, (n,)) * 0.01
     av = jnp.zeros((n,))
-    us = _time_us(lambda: sga_update(wv, gv, av, lr=1 / 16, g_th=0.078125))
+    us = _time_us(lambda: sga_update(wv, gv, av, lr=1 / 16, g_th=0.078125,
+                                     interpret=interpret))
     us_ref = _time_us(jax.jit(
         lambda: sga_update_ref(wv, gv, av, 1 / 16, 0.078125)))
     _row("kernel_sga_update_8192", f"{us:.0f}", f"ref_us={us_ref:.0f}")
@@ -1672,11 +1676,12 @@ def main(argv=None) -> None:
                  "--compiled")
     if args.devices < 1:
         ap.error("--devices must be >= 1")
-    if args.devices > 1:
-        # must land before the first jax import anywhere in the process:
-        # the host-platform device count locks on backend initialization
-        # (harmless on real multi-device backends — jax ignores the flag
-        # off-CPU; appended last so it wins over an inherited setting)
+    if args.devices > 1 and os.environ.get("JAX_PLATFORMS") == "cpu":
+        # a CPU-only run gets N host-platform devices; must land before
+        # the first jax import anywhere in the process (the count locks
+        # on backend initialization; appended last so it wins over an
+        # inherited setting).  On an accelerator the N devices must
+        # exist: ShardedStreamServer refuses to run on fewer.
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={args.devices}")
@@ -1701,6 +1706,8 @@ def main(argv=None) -> None:
             n = _TRACE.dump(args.trace_out)
             _row("trace_json", "", f"{args.trace_out};spans={n}")
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     if args.imc_fused:
         batches = tuple(int(b) for b in

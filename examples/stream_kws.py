@@ -1,9 +1,8 @@
 """Always-on streaming KWS quickstart: a few live audio streams through the
 multi-stream serving engine.
 
-  1. fold a model to the hardware path (reuses the cached trained model
-     from benchmarks.kws_experiments if present, else folds an untrained
-     one — the serving mechanics are identical),
+  1. fold a seeded, untrained model to the hardware path (the serving
+     mechanics are those of a trained one),
   2. synthesize a few "microphone" streams: keyword utterances embedded in
      noise at random offsets,
   3. run the slot-based StreamServer with voice-activity gating: every
@@ -23,7 +22,6 @@ Run:  PYTHONPATH=src python examples/stream_kws.py
       tests/test_examples.py)
 """
 import os
-import pickle
 
 import jax
 import numpy as np
@@ -38,21 +36,8 @@ N_STREAMS = 1 if SMOKE else 3
 TAIL_HOPS = 8 if SMOKE else 24
 cfg = m.KWSConfig(sample_len=L)
 
-pkl = os.path.join(os.path.dirname(__file__), "..", "results",
-                   "kws_model.pkl")
-if os.path.exists(pkl) and not SMOKE:
-    with open(pkl, "rb") as f:
-        params, state = pickle.load(f)
-    params = jax.tree_util.tree_map(np.asarray, params)
-    state = m.KWSState(*[jax.tree_util.tree_map(np.asarray, s)
-                         for s in state])
-    print("== folded the trained model from results/kws_model.pkl ==")
-else:
-    params = m.init_params(jax.random.PRNGKey(0), cfg)
-    state = m.init_state(cfg)
-    print("== no cached model (run benchmarks.kws_experiments for a "
-          "trained one); folding an untrained net to demo the serving "
-          "path ==")
+params = m.init_params(jax.random.PRNGKey(0), cfg)
+state = m.init_state(cfg)
 hw = m.fold_params(params, state, cfg, pack=True)   # pack once, serve many
 
 # synth streams: keyword clips at random offsets in low noise

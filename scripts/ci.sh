@@ -84,8 +84,8 @@ XLA_FLAGS="${XLA_FLAGS:-} --xla_force_host_platform_device_count=2" \
     python -m pytest -x -q tests/test_sharded_serving.py -m "not slow"
 XLA_FLAGS="${XLA_FLAGS:-} --xla_force_host_platform_device_count=2" \
     python -m pytest -x -q tests/test_obs.py -k "device or sharded"
-python -m benchmarks.run --streaming --devices 2 --stream-hops 2 \
-    --streaming-out "$(mktemp -d)/BENCH_streaming.json" > /dev/null
+JAX_PLATFORMS=cpu python -m benchmarks.run --streaming --devices 2 \
+    --stream-hops 2 --streaming-out "$(mktemp -d)/BENCH_streaming.json" > /dev/null
 # compiled-tick gate (docs/SERVING.md): the whole-tick fast path is
 # bit-identical to the interpreted tick — a quick differential slice
 # (gated + noise/chip configs, single-tick block routing, the byte-pinned

@@ -190,13 +190,19 @@ def binary_group_conv_counts(x_bin: jax.Array, w_bin: jax.Array,
     x_bin: (B, T, C_in) in {-1,+1};  w_bin: (K, C_in//groups, C_out) in {-1,+1}.
     Returns (B, T_out, C_out) integer-valued counts (sum of +/-1 products) —
     exactly what accumulates on the AVG lines before the SA.
+
+    ``HIGHEST`` precision keeps the counts exact on a TPU: at the default
+    precision a grouped convolution of ±1 float32 operands came back off
+    by up to 24 counts on a v5e (jax 0.9.0), and the digital layer 0, whose
+    input is 8-bit audio, would round its operands to bf16.
     """
     dn = jax.lax.conv_dimension_numbers(x_bin.shape, w_bin.shape,
                                         ("NWC", "WIO", "NWC"))
     out = jax.lax.conv_general_dilated(
         x_bin.astype(jnp.float32), w_bin.astype(jnp.float32),
         window_strides=(stride,), padding="VALID",
-        dimension_numbers=dn, feature_group_count=groups)
+        dimension_numbers=dn, feature_group_count=groups,
+        precision=jax.lax.Precision.HIGHEST)
     return out
 
 

@@ -30,12 +30,14 @@ Two kernels live here:
   instead of each padding to 128.  The epilogue fuses the entire digital
   block after the macro — static chip offset, integer word-line bias, SA
   noise, BN-decoder flip, SA sign, OR-maxpool over ``pool`` adjacent window
-  positions — and the channel shuffle is realized as the *output index map*:
-  the output array is (M/pool, cog, groups) with pack p writing lane-slab
-  [..., p*gpb:(p+1)*gpb], which flattens to the shuffled channel order
-  a*groups + g with no separate shuffle pass.  ±1 activations therefore go
-  conv -> pool without any pre-activation ever touching HBM, mirroring how
-  the macro never digitizes the analog MAV value.
+  positions.  Every block is Mosaic-tileable: a pool window's ``pool``
+  patch rows sit side by side on the lane axis (a free row-major reshape),
+  so the OR-pool is an elementwise max of ``pool`` matmul epilogues, and
+  the kernel writes a lane-dense (packs, M/pool, n_pad) slab.  The channel
+  shuffle is one XLA transpose of that slab to (M/pool, cog, groups), which
+  flattens to the shuffled channel order a*groups + g.  ±1 activations
+  therefore go conv -> pool without any pre-activation ever touching HBM,
+  mirroring how the macro never digitizes the analog MAV value.
 """
 
 from __future__ import annotations
@@ -66,7 +68,7 @@ def _mav_kernel_noise(x_ref, w_ref, b_ref, f_ref, n_ref, o_ref):
                    static_argnames=("bm", "bn", "interpret"))
 def imc_mav(x: jax.Array, w: jax.Array, bias: jax.Array, flip: jax.Array,
             noise: jax.Array | None = None, *, bm: int = 256, bn: int = 128,
-            interpret: bool = True) -> jax.Array:
+            interpret: bool) -> jax.Array:
     """sign((x @ w + bias [+ noise]) * flip) with VMEM-fused epilogue.
 
     x: (M, K) ±1; w: (K, N) ±1; bias/flip: (N,); noise: (M, N) or None.
@@ -99,41 +101,37 @@ def imc_mav(x: jax.Array, w: jax.Array, bias: jax.Array, flip: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# Whole-layer fused kernel (grouped conv + epilogue + shuffle + OR-pool)
+# Whole-layer fused kernel (grouped conv + epilogue + OR-pool)
 # ---------------------------------------------------------------------------
 
 
-def _epilogue(counts, off, bias, flip, noise, o_ref, *, gpb, cog, pool):
-    """Shared fused epilogue: ((counts + off) + bias [+ noise]) * flip ->
-    sign -> OR-maxpool over `pool` adjacent rows -> (rows/pool, cog, gpb).
+def _fused_kernel(x_ref, w_ref, off_ref, b_ref, f_ref, *rest, pool, k_pad,
+                  n_pad):
+    """One (pack, M-tile) step: ``pool`` matmuls, one per pool phase, each
+    with the fused epilogue ((counts + off) + bias [+ noise]) * flip ->
+    sign, then the OR-maxpool as an elementwise max across the phases.
 
-    The float-add order matches core.imc.mav_sa exactly (counts + chip
-    offset, then bias, then SA noise, then the BN-decoder flip) so the fused
-    path is bit-identical to the jnp oracle, noise included."""
-    pre = (counts + off[None, :]) + bias[None, :]
-    if noise is not None:
-        pre = pre + noise
-    pre = pre * flip[None, :]
-    act = jnp.where(pre >= 0, 1.0, -1.0)
-    act = act[:, :gpb * cog].reshape(-1, pool, gpb, cog)
-    act = jnp.max(act, axis=1)                       # OR-pool on ±1 == max
-    o_ref[...] = jnp.transpose(act, (0, 2, 1)).astype(o_ref.dtype)
-
-
-def _fused_kernel(x_ref, w_ref, off_ref, b_ref, f_ref, o_ref, *,
-                  gpb, cog, pool):
-    counts = jnp.dot(x_ref[...], w_ref[...],
-                     preferred_element_type=jnp.float32)
-    _epilogue(counts, off_ref[...], b_ref[...], f_ref[...], None, o_ref,
-              gpb=gpb, cog=cog, pool=pool)
-
-
-def _fused_kernel_noise(x_ref, w_ref, off_ref, b_ref, f_ref, n_ref, o_ref, *,
-                        gpb, cog, pool):
-    counts = jnp.dot(x_ref[...], w_ref[...],
-                     preferred_element_type=jnp.float32)
-    _epilogue(counts, off_ref[...], b_ref[...], f_ref[...], n_ref[...],
-              o_ref, gpb=gpb, cog=cog, pool=pool)
+    Row r of the x block holds the ``pool`` adjacent window positions of
+    output row r side by side on the lane axis (phase j at lanes
+    [j*k_pad, (j+1)*k_pad)), so every slice here is lane-aligned and the
+    pool never reshapes across sublanes.  The float-add order matches
+    core.imc.mav_sa exactly (counts + chip offset, then bias, then SA
+    noise, then the BN-decoder flip), so the fused path is bit-identical
+    to the jnp oracle, noise included."""
+    n_ref = rest[0] if len(rest) == 2 else None
+    o_ref = rest[-1]
+    w = w_ref[...]
+    off, bias, flip = off_ref[...], b_ref[...], f_ref[...]     # (1, n_pad)
+    out = None
+    for j in range(pool):
+        counts = jnp.dot(x_ref[:, j * k_pad:(j + 1) * k_pad], w,
+                         preferred_element_type=jnp.float32)
+        pre = (counts + off) + bias
+        if n_ref is not None:
+            pre = pre + n_ref[:, j * n_pad:(j + 1) * n_pad]
+        act = jnp.where(pre * flip >= 0, 1.0, -1.0)
+        out = act if out is None else jnp.maximum(out, act)   # OR-pool
+    o_ref[...] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -141,7 +139,7 @@ def _fused_kernel_noise(x_ref, w_ref, off_ref, b_ref, f_ref, n_ref, o_ref, *,
 def imc_fused(xp: jax.Array, wp: jax.Array, off: jax.Array, bias: jax.Array,
               flip: jax.Array, noise: jax.Array | None = None, *,
               gpb: int, cog: int, pool: int = 1, bm: int = 256,
-              interpret: bool = True) -> jax.Array:
+              interpret: bool) -> jax.Array:
     """One ``pallas_call`` for a whole grouped IMC layer.
 
     xp:   (packs, M, k_pad)  packed ±1 im2col patches (zero K-padding);
@@ -152,28 +150,36 @@ def imc_fused(xp: jax.Array, wp: jax.Array, off: jax.Array, bias: jax.Array,
 
     M must be a multiple of ``bm`` and ``bm`` a multiple of ``pool`` (the
     caller pads on pool-window boundaries, so padded rows never share an
-    OR-pool window with real rows).  Returns (M // pool, cog, packs*gpb):
-    flattening the last two axes is exactly the post-shuffle channel order.
+    OR-pool window with real rows).  The kernel writes a lane-dense
+    (packs, M // pool, n_pad) slab; the channel shuffle is one XLA
+    transpose after it.  Returns (M // pool, cog, packs*gpb): flattening
+    the last two axes is exactly the post-shuffle channel order.
     """
     packs, m, k_pad = xp.shape
     n_pad = wp.shape[-1]
+    bm_out = bm // pool
     grid = (packs, m // bm)
-    x_spec = pl.BlockSpec((None, bm, k_pad), lambda p, i: (p, i, 0))
+    # free row-major reshapes: a pool window's rows become one row
+    xr = xp.reshape(packs, m // pool, pool * k_pad)
+    x_spec = pl.BlockSpec((None, bm_out, pool * k_pad),
+                          lambda p, i: (p, i, 0))
     w_spec = pl.BlockSpec((None, k_pad, n_pad), lambda p, i: (p, 0, 0))
-    c_spec = pl.BlockSpec((None, n_pad), lambda p, i: (p, 0))
-    o_spec = pl.BlockSpec((bm // pool, cog, gpb), lambda p, i: (i, 0, p))
-    out_shape = jax.ShapeDtypeStruct((m // pool, cog, packs * gpb), xp.dtype)
-    if noise is None:
-        return pl.pallas_call(
-            functools.partial(_fused_kernel, gpb=gpb, cog=cog, pool=pool),
-            grid=grid,
-            in_specs=[x_spec, w_spec, c_spec, c_spec, c_spec],
-            out_specs=o_spec, out_shape=out_shape, interpret=interpret,
-        )(xp, wp, off, bias, flip)
-    n_spec = pl.BlockSpec((None, bm, n_pad), lambda p, i: (p, i, 0))
-    return pl.pallas_call(
-        functools.partial(_fused_kernel_noise, gpb=gpb, cog=cog, pool=pool),
-        grid=grid,
-        in_specs=[x_spec, w_spec, c_spec, c_spec, c_spec, n_spec],
-        out_specs=o_spec, out_shape=out_shape, interpret=interpret,
-    )(xp, wp, off, bias, flip, noise)
+    c_spec = pl.BlockSpec((None, 1, n_pad), lambda p, i: (p, 0, 0))
+    o_spec = pl.BlockSpec((None, bm_out, n_pad), lambda p, i: (p, i, 0))
+    chans = [v.reshape(packs, 1, n_pad) for v in (off, bias, flip)]
+    operands = [xr, wp, *chans]
+    in_specs = [x_spec, w_spec, c_spec, c_spec, c_spec]
+    if noise is not None:
+        operands.append(noise.reshape(packs, m // pool, pool * n_pad))
+        in_specs.append(pl.BlockSpec((None, bm_out, pool * n_pad),
+                                     lambda p, i: (p, i, 0)))
+    out = pl.pallas_call(
+        functools.partial(_fused_kernel, pool=pool, k_pad=k_pad,
+                          n_pad=n_pad),
+        grid=grid, in_specs=in_specs, out_specs=o_spec,
+        out_shape=jax.ShapeDtypeStruct((packs, m // pool, n_pad), xp.dtype),
+        interpret=interpret,
+    )(*operands)
+    # channel shuffle: pack p, slot s, channel a -> (a, group p*gpb + s)
+    out = out[:, :, :gpb * cog].reshape(packs, m // pool, gpb, cog)
+    return out.transpose(1, 3, 0, 2).reshape(m // pool, cog, packs * gpb)

@@ -132,8 +132,8 @@ def fused_conv_mav(x: jax.Array, w: jax.Array, bias: jax.Array,
 
     x: (B, T, C_in) ±1;  w: (K, C_in//groups, C_out) ±1;
     bias/flip/chip_offset: (C_out,).  Returns (B, T_pool, C_out) ±1 in the
-    *post-shuffle* channel order — the shuffle is the kernel's output index
-    map (see imc_mav.py), not a separate pass.
+    *post-shuffle* channel order — the shuffle is one transpose of the
+    kernel's lane-dense output (see imc_mav.py).
 
     The SA noise realization is either drawn here from ``sa_key`` (same
     key/shape as ``core.imc.mav_sa``) or supplied explicitly as ``sa_noise``

@@ -5,7 +5,7 @@ The digital FC classifier + on-chip-training datapath of the chip (§V-C):
 (multiplication by the error-scaling factor 1.375 = shift-and-add on chip;
 here the shift exponent is a kernel scalar).  No float in the datapath.
 
-MXU note: TPU MXUs execute s8xs8->s32 natively; interpret=True validates the
+MXU note: TPU MXUs execute s8xs8->s32 natively; interpret mode validates the
 integer semantics on CPU bit-exactly.
 """
 
@@ -33,7 +33,7 @@ def _int8_kernel(x_ref, w_ref, b_ref, o_ref, *, shift: int, out_max: int):
                                              "interpret"))
 def int8_matmul(x: jax.Array, w: jax.Array, bias: jax.Array, *,
                 shift: int = 7, out_max: int = 127, bm: int = 256,
-                bn: int = 128, interpret: bool = True) -> jax.Array:
+                bn: int = 128, interpret: bool) -> jax.Array:
     """x: (M, K) int8, w: (K, N) int8, bias: (N,) int32 ->
     (M, N) int8 codes = clip((x@w + bias) >> shift)."""
     m, k = x.shape

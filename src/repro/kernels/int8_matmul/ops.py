@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.quantize import ACT_Q, WEIGHT_Q, QFormat
+from repro.kernels import default_interpret
 from repro.kernels.int8_matmul.int8_matmul import int8_matmul
 
 
@@ -20,7 +21,7 @@ def _pad(x, axis, mult, value=0):
 
 def quantized_fc(feats: jax.Array, w: jax.Array, b: jax.Array,
                  act_fmt: QFormat = ACT_Q, w_fmt: QFormat = WEIGHT_Q,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool | None = None) -> jax.Array:
     """Value-domain FC through the int8 kernel.
 
     feats real (M, D) -> codes via act_fmt; w/b via w_fmt.  The product grid
@@ -28,6 +29,8 @@ def quantized_fc(feats: jax.Array, w: jax.Array, b: jax.Array,
     act_fmt's grid: shift = frac(act)+frac(w) - frac(act) = frac(w).
     Returns real values on the act grid, shape (M, N).
     """
+    if interpret is None:
+        interpret = default_interpret()
     m0, n0 = feats.shape[0], w.shape[1]
     xq = act_fmt.to_int(feats, jnp.int8)
     wq = w_fmt.to_int(w, jnp.int8)

@@ -11,8 +11,10 @@ from repro.kernels.sga_update.sga_update import sga_update, sga_update_rows
 
 
 def sga_update_tree(params, grads, accums, lr: float, g_th: float,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """Apply the fused update leaf-wise; shapes preserved."""
+    if interpret is None:
+        interpret = default_interpret()
     leaves_w, treedef = jax.tree_util.tree_flatten(params)
     leaves_g = treedef.flatten_up_to(grads)
     leaves_a = treedef.flatten_up_to(accums)

@@ -14,6 +14,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _sga_kernel(w_ref, g_ref, a_ref, wo_ref, ao_ref, *,
@@ -37,8 +38,9 @@ def _sga_rows_kernel(lr_ref, th_ref, w_ref, g_ref, a_ref, wo_ref, ao_ref, *,
     """Row-batched variant: each grid row is one session's flattened
     optimizer state with its OWN (lr, g_th) scalars — the learning-rate
     schedule position differs per enrollment session, so the scalars ride
-    as operands instead of static compile-time constants."""
-    lr, g_th = lr_ref[0, 0], th_ref[0, 0]
+    in SMEM as operands instead of static compile-time constants."""
+    row = pl.program_id(0)
+    lr, g_th = lr_ref[row], th_ref[row]
     w, g, a = w_ref[...], g_ref[...], a_ref[...]
     small = jnp.abs(g) < g_th
     banked = jnp.round((a + jnp.where(small, g, 0.0)) / a_scale) * a_scale
@@ -52,13 +54,16 @@ def _sga_rows_kernel(lr_ref, th_ref, w_ref, g_ref, a_ref, wo_ref, ao_ref, *,
     ao_ref[...] = new_a.astype(ao_ref.dtype)
 
 
+_LANES = 128
+
+
 @functools.partial(jax.jit, static_argnames=("w_scale", "w_max", "a_scale",
                                              "block", "interpret"))
 def sga_update_rows(w: jax.Array, g: jax.Array, accum: jax.Array,
                     lr: jax.Array, g_th: jax.Array, *,
                     w_scale: float = 1.0 / 128, w_max: float = 127.0 / 128,
                     a_scale: float = 2.0 ** -15, block: int = 1024,
-                    interpret: bool = True):
+                    interpret: bool):
     """Batched fused SGA update: one ``pallas_call`` for B sessions.
 
     w/g/accum: (B, N) with N % block == 0 (ops.py pads); lr/g_th: (B,)
@@ -66,21 +71,26 @@ def sga_update_rows(w: jax.Array, g: jax.Array, accum: jax.Array,
     ``sga_update(w[b], g[b], accum[b], lr=lr[b], g_th=g_th[b])`` — the
     serving customization scheduler stacks every active session's
     (head, bias, SGA bank) into rows so a mixed tick's optimizer work is
-    one launch regardless of how many users are enrolling."""
+    one launch regardless of how many users are enrolling.  Each row is
+    viewed as (N / 128, 128) so a block is a full (block / 128, 128)
+    sublane x lane tile."""
     b, n = w.shape
     kern = functools.partial(_sga_rows_kernel, w_scale=w_scale, w_max=w_max,
                              a_scale=a_scale)
-    spec = pl.BlockSpec((1, block), lambda i, j: (i, j))
-    s_spec = pl.BlockSpec((1, 1), lambda i, j: (i, 0))
-    return pl.pallas_call(
+    rows = block // _LANES
+    spec = pl.BlockSpec((None, rows, _LANES), lambda i, j: (i, j, 0))
+    s_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
+    shape3 = (b, n // _LANES, _LANES)
+    nw, na = pl.pallas_call(
         kern, grid=(b, n // block),
         in_specs=[s_spec, s_spec, spec, spec, spec],
         out_specs=(spec, spec),
-        out_shape=(jax.ShapeDtypeStruct((b, n), w.dtype),
-                   jax.ShapeDtypeStruct((b, n), accum.dtype)),
+        out_shape=(jax.ShapeDtypeStruct(shape3, w.dtype),
+                   jax.ShapeDtypeStruct(shape3, accum.dtype)),
         interpret=interpret,
-    )(lr.reshape(b, 1).astype(jnp.float32),
-      g_th.reshape(b, 1).astype(jnp.float32), w, g, accum)
+    )(lr.reshape(b).astype(jnp.float32), g_th.reshape(b).astype(jnp.float32),
+      w.reshape(shape3), g.reshape(shape3), accum.reshape(shape3))
+    return nw.reshape(b, n), na.reshape(b, n)
 
 
 @functools.partial(jax.jit, static_argnames=("lr", "g_th", "w_scale",
@@ -89,7 +99,7 @@ def sga_update_rows(w: jax.Array, g: jax.Array, accum: jax.Array,
 def sga_update(w: jax.Array, g: jax.Array, accum: jax.Array, *,
                lr: float, g_th: float, w_scale: float = 1.0 / 128,
                w_max: float = 127.0 / 128, a_scale: float = 2.0 ** -15,
-               block: int = 1024, interpret: bool = True):
+               block: int = 1024, interpret: bool):
     """All inputs flat (N,) with N % block == 0 (ops.py pads).
     Returns (new_w, new_accum)."""
     n = w.shape[0]

@@ -42,10 +42,11 @@ bit-identically.
 
 Device binding follows ``launch/mesh.py``'s idiom — devices are resolved
 at construction time, never at import time: ``devices=N`` takes the
-first N entries of ``jax.devices()`` (wrapping if fewer exist, which is
-how the equivalence tests run N logical pools on one physical device;
-CI gets real host-platform devices via
-``XLA_FLAGS=--xla_force_host_platform_device_count``).
+first N entries of ``jax.devices()`` and raises when fewer exist, so a
+fleet never runs on fewer chips than it names.  N logical pools on one
+physical device is an explicit device list (``[dev] * N``), which is how
+the equivalence tests run on one CPU device; real host-platform devices
+come from ``XLA_FLAGS=--xla_force_host_platform_device_count``.
 """
 
 from __future__ import annotations
@@ -83,12 +84,13 @@ class ShardedStreamServer:
                  faults=None,
                  seed: int = 0,
                  **server_kw):
-        """``devices`` is a count (resolved against ``jax.devices()`` at
-        construction, wrapping when fewer physical devices exist) or an
-        explicit device sequence.  ``slots`` is PER DEVICE.  ``faults``
-        must be a ``FaultConfig`` (each pool builds its own seeded
-        ``FaultModel`` so injections replay identically per pool) — a
-        shared ``FaultModel`` instance would double-tick across pools.
+        """``devices`` is a count (the first N of ``jax.devices()`` at
+        construction; fewer available devices is an error) or an
+        explicit device sequence, which may repeat a device.  ``slots``
+        is PER DEVICE.  ``faults`` must be a ``FaultConfig`` (each pool
+        builds its own seeded ``FaultModel`` so injections replay
+        identically per pool) — a shared ``FaultModel`` instance would
+        double-tick across pools.
         Remaining ``server_kw`` is forwarded verbatim to every pool.
 
         ``parallel=True`` dispatches pool ticks on one thread per device
@@ -99,7 +101,12 @@ class ShardedStreamServer:
             if devices < 1:
                 raise ValueError("devices must be >= 1")
             avail = jax.devices()
-            self.devices = [avail[d % len(avail)] for d in range(devices)]
+            if len(avail) < devices:
+                raise ValueError(
+                    f"devices={devices} but only {len(avail)} "
+                    f"{avail[0].platform} device(s) exist; pass an "
+                    f"explicit device list to share devices")
+            self.devices = avail[:devices]
         else:
             self.devices = list(devices)
         if faults is not None:

@@ -26,7 +26,7 @@ import numpy as np
 __all__ = [
     "COUNTER_EXCLUDES", "advance_to", "assert_counters_equal",
     "assert_events_equal", "assert_leaves_equal", "assert_server_equal",
-    "counter_cells", "per_stream",
+    "counter_cells", "per_stream", "pool_devices",
 ]
 
 # registry names excluded from counter equality: wall time is physical,
@@ -151,3 +151,12 @@ def advance_to(srv, ticks):
         while srv._steps < ticks:
             events.extend(srv.step())
     return events
+
+
+def pool_devices(n):
+    """An explicit device list for an ``n``-pool sharded fleet: the first
+    ``n`` devices, reusing them in turn when fewer exist, so one CPU
+    device hosts ``n`` logical pools (``ShardedStreamServer(devices=n)``
+    itself refuses to run on fewer than ``n`` devices)."""
+    avail = jax.devices()
+    return [avail[d % len(avail)] for d in range(n)]

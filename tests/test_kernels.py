@@ -1,4 +1,4 @@
-"""Per-kernel validation vs the pure-jnp oracles (interpret=True on CPU),
+"""Per-kernel validation vs the pure-jnp oracles (interpret mode on CPU),
 sweeping shapes and dtypes per the deliverable."""
 
 import jax
@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core.quantize import ACT_Q, WEIGHT_Q
+from repro.kernels import default_interpret
 from repro.kernels.imc_mav import ops as mav_ops
 from repro.kernels.imc_mav.ref import imc_mav_ref
 from repro.kernels.int8_matmul.int8_matmul import int8_matmul
@@ -76,7 +77,7 @@ def test_int8_matmul_bitexact(m, k, n, shift):
                            jnp.int8)
     b = jax.random.randint(jax.random.fold_in(key, 2), (n,), -1000, 1000,
                            jnp.int32)
-    out = int8_matmul(x, w, b, shift=shift)
+    out = int8_matmul(x, w, b, shift=shift, interpret=default_interpret())
     ref = int8_matmul_ref(x, w, b, shift=shift)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 

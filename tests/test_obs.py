@@ -25,6 +25,8 @@ import jax
 import numpy as np
 import pytest
 
+import _equiv as eq
+
 from repro.core import faults as flt
 from repro.core import imc
 from repro.models import kws as m
@@ -543,7 +545,8 @@ def test_sharded_per_device_one_launch_audit(folded, monkeypatch):
 
     monkeypatch.setenv("REPRO_OBS_AUDIT", "raise")
     rng = np.random.default_rng(21)
-    sh = ShardedStreamServer(folded, CFG, devices=2, slots=3, hop=HOP,
+    sh = ShardedStreamServer(folded, CFG, devices=eq.pool_devices(2),
+                             slots=3, hop=HOP,
                              use_kernel=True, vad=_VAD, seed=3,
                              health=HealthConfig(interval=4))
     sess = sh.customize("u0", CustomizeConfig(

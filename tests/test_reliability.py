@@ -634,7 +634,8 @@ def _sharded_soak(folded, seed, ticks, snapshot_every):
 
     def mk():
         return ShardedStreamServer(
-            hw, CFG, devices=2, slots=2, hop=HOP, use_kernel=False,
+            hw, CFG, devices=eq.pool_devices(2), slots=2, hop=HOP,
+            use_kernel=False,
             chip_offsets=chip, sa_noise_std=1.0, vad=VADConfig(),
             faults=flt.FaultConfig(drift_std=0.1, seed=seed),
             health=HealthConfig(interval=5), seed=seed)

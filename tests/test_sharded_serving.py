@@ -18,8 +18,9 @@
   rollup (the tier's only cross-device gather) sums the per-device pool
   counters with zero launch-audit violations.
 
-Pools map to ``jax.devices()[d % len(devices)]``, so this file runs N
-logical pools on one physical device; the CI sharding gate re-runs it
+Pools get the explicit list ``_equiv.pool_devices(N)``, which reuses
+devices in turn, so this file runs N logical pools on one physical
+device; the CI sharding gate re-runs it
 under ``XLA_FLAGS=--xla_force_host_platform_device_count=2`` for real
 per-device placement.
 """
@@ -118,7 +119,8 @@ def test_sharded_bitident_noise_and_chip_offsets(folded):
     noise field must equal the one the oracle drew for its slot."""
     kw = dict(hop=HOP, sa_noise_std=0.3, chip_offsets=_chip(), seed=0)
     oracle = StreamServer(folded, CFG, slots=4, **kw)
-    sh = ShardedStreamServer(folded, CFG, devices=2, slots=2, **kw)
+    sh = ShardedStreamServer(folded, CFG, devices=eq.pool_devices(2),
+                             slots=2, **kw)
     wavs = {f"s{i}": _wav(100 + i, L + 6 * HOP) for i in range(4)}
     for sid, w in wavs.items():
         oracle.submit(sid, w)
@@ -141,7 +143,8 @@ def test_sharded_bitident_vad_gating(folded):
                     wake_margin=1, hang=0)
     kw = dict(hop=HOP, sa_noise_std=0.2, vad=vad, seed=0)
     oracle = StreamServer(folded, CFG, slots=4, **kw)
-    sh = ShardedStreamServer(folded, CFG, devices=2, slots=2, **kw)
+    sh = ShardedStreamServer(folded, CFG, devices=eq.pool_devices(2),
+                             slots=2, **kw)
     rng = np.random.default_rng(11)
     for i in range(4):
         w = rng.uniform(-1, 1, L + 12 * HOP).astype(np.float32)
@@ -166,7 +169,7 @@ def test_sharded_bitident_faults_and_drift(folded):
     fcfg = flt.FaultConfig(drift_std=0.2, seed=3)
     kw = dict(hop=HOP, sa_noise_std=0.2, seed=0)
     oracle = StreamServer(folded, CFG, slots=4, faults=fcfg, **kw)
-    sh = ShardedStreamServer(folded, CFG, devices=2, slots=2,
+    sh = ShardedStreamServer(folded, CFG, devices=eq.pool_devices(2), slots=2,
                              faults=fcfg, **kw)
     assert len(sh.fault_models) == 2
     for i in range(4):
@@ -189,7 +192,7 @@ def test_sharded_bitident_faults_and_drift(folded):
     _assert_equiv(ev_o, ev_s)
     # a shared FaultModel instance would double-tick across pools
     with pytest.raises(ValueError):
-        ShardedStreamServer(folded, CFG, devices=2, slots=2,
+        ShardedStreamServer(folded, CFG, devices=eq.pool_devices(2), slots=2,
                             faults=oracle.faults, **kw)
 
 
@@ -205,7 +208,8 @@ def test_sharded_snapshot_restore_bit_identical(folded, tmp_path):
     oracle = StreamServer(folded, CFG, slots=4, **kw)
 
     def mk():
-        return ShardedStreamServer(folded, CFG, devices=2, slots=2, **kw)
+        return ShardedStreamServer(folded, CFG, devices=eq.pool_devices(2),
+                                   slots=2, **kw)
 
     sh = mk()
     for i in range(4):
@@ -230,8 +234,20 @@ def test_sharded_snapshot_restore_bit_identical(folded, tmp_path):
     assert sum(len(v) for v in po.values()) > 0
     # a fleet of the wrong width must refuse the bundle
     with pytest.raises(ValueError):
-        ShardedStreamServer(folded, CFG, devices=3, slots=2,
+        ShardedStreamServer(folded, CFG, devices=eq.pool_devices(3), slots=2,
                             **kw).restore(path)
+
+
+def test_device_count_beyond_available_raises(folded):
+    """``devices=N`` never reuses a device: asking for more pools than
+    ``jax.devices()`` holds is an error, not a silent wrap onto device 0
+    (an explicit list is how N pools share devices on purpose)."""
+    n = len(jax.devices())
+    with pytest.raises(ValueError, match="explicit device list"):
+        ShardedStreamServer(folded, CFG, hop=HOP, devices=n + 1, slots=1)
+    sh = ShardedStreamServer(folded, CFG, hop=HOP,
+                             devices=eq.pool_devices(n + 1), slots=1)
+    assert sh.n_devices == n + 1
 
 
 @pytest.mark.streaming
@@ -243,7 +259,8 @@ def test_router_rejection_consumes_no_uid(folded):
     from repro.serving import AdmissionConfig
     kw = dict(hop=HOP, seed=0,
               admission=AdmissionConfig(max_queue=0))
-    sh = ShardedStreamServer(folded, CFG, devices=2, slots=1, **kw)
+    sh = ShardedStreamServer(folded, CFG, devices=eq.pool_devices(2),
+                             slots=1, **kw)
     for i in range(2):                      # fill both pools' only slots
         assert sh.submit(f"s{i}", _wav(i, L)) == "slot"
     uid_before = sh._next_uid
@@ -260,7 +277,8 @@ def test_events_device_tags_and_fleet_rollup(folded):
     the router's placement), and the fleet stats rollup equals the sum
     of the per-device pools with zero audit violations."""
     obs = ObsConfig(recorder=32, audit="raise", trace=False)
-    sh = ShardedStreamServer(folded, CFG, devices=2, slots=2, hop=HOP,
+    sh = ShardedStreamServer(folded, CFG, devices=eq.pool_devices(2),
+                             slots=2, hop=HOP,
                              seed=0, obs=obs)
     for i in range(4):
         sh.submit(f"s{i}", _wav(500 + i, L + 4 * HOP))
@@ -299,7 +317,8 @@ def _dual_soak(folded, seed, ticks=12):
     oracle = StreamServer(hw, CFG, slots=4, **kw)
 
     def mk():
-        return ShardedStreamServer(hw, CFG, devices=2, slots=2, **kw)
+        return ShardedStreamServer(hw, CFG, devices=eq.pool_devices(2),
+                                   slots=2, **kw)
 
     sh = mk()
     rng = np.random.default_rng(seed)
