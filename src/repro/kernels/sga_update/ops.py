@@ -35,6 +35,7 @@ def sga_update_tree(params, grads, accums, lr: float, g_th: float,
             jax.tree_util.tree_unflatten(treedef, new_a))
 
 
+@jax.named_scope("sga_update")
 def sga_update_batch(w: jax.Array, g: jax.Array, accum: jax.Array,
                      lr: jax.Array, g_th: jax.Array, *,
                      w_scale: float = 1.0 / 128, w_max: float = 127.0 / 128,

@@ -1,6 +1,6 @@
 """Observability for the serving stack (``repro.obs``).
 
-Four pieces, all optional except the registry:
+The pieces, all optional except the registry and the spans:
 
 * :class:`MetricsRegistry` — labelled counters/gauges/histograms; the
   serving classes' ``stats()`` dicts are views over one shared registry,
@@ -10,14 +10,17 @@ Four pieces, all optional except the registry:
 * :class:`LaunchAuditor` — opt-in runtime interceptor enforcing the
   one-fused-launch-per-IMC-layer-per-tick contract, with ``flag`` and
   ``raise`` modes.
-* :class:`TraceBuilder` — per-tick spans exported as Chrome/Perfetto
-  trace JSON.
+* :func:`span` — ``serving.*`` host spans on the JAX profiler's clock,
+  always on (``jax.profiler.trace`` records them beside the device's
+  ops; without a profiler session they record nothing).
+* :mod:`compiles` — a process-wide tally of jit traces, backend compiles
+  and persistent-cache loads, booked per step as ``serving.compiles``.
 
 ``ObsConfig`` selects which extras a ``StreamServer`` turns on; the
 default (all off) is bit-identical to — and within noise as fast as —
 the pre-telemetry server.  ``ObsConfig.from_env()`` reads
-``REPRO_OBS_AUDIT`` / ``REPRO_OBS_RECORDER`` / ``REPRO_OBS_TRACE`` so CI
-can flip the auditor on without touching call sites.
+``REPRO_OBS_AUDIT`` / ``REPRO_OBS_RECORDER`` so CI can flip the auditor
+on without touching call sites.
 """
 
 from __future__ import annotations
@@ -25,10 +28,11 @@ from __future__ import annotations
 import os
 from dataclasses import dataclass
 
+from . import compiles
 from .audit import AUDIT_MODES, LaunchAuditError, LaunchAuditor
 from .metrics import MetricsRegistry, counter_property
 from .recorder import FlightRecorder
-from .trace import TraceBuilder
+from .trace import span
 
 __all__ = [
     "AUDIT_MODES",
@@ -37,8 +41,9 @@ __all__ = [
     "LaunchAuditor",
     "MetricsRegistry",
     "ObsConfig",
-    "TraceBuilder",
+    "compiles",
     "counter_property",
+    "span",
 ]
 
 
@@ -48,13 +53,10 @@ class ObsConfig:
 
     recorder   flight-recorder ring capacity in events; 0 disables it.
     audit      launch-auditor mode: "off", "flag" or "raise".
-    trace      collect per-tick Perfetto spans (dump via
-               ``StreamServer.trace.dump(path)``).
     """
 
     recorder: int = 0
     audit: str = "off"
-    trace: bool = False
 
     def __post_init__(self):
         if self.audit not in AUDIT_MODES:
@@ -69,6 +71,4 @@ class ObsConfig:
         return cls(
             recorder=int(os.environ.get("REPRO_OBS_RECORDER", "0")),
             audit=os.environ.get("REPRO_OBS_AUDIT", "off"),
-            trace=os.environ.get("REPRO_OBS_TRACE", "") not in
-            ("", "0", "false"),
         )

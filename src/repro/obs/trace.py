@@ -1,85 +1,37 @@
-"""Chrome/Perfetto trace export for the serving tick.
+"""Host spans on the JAX profiler's clock.
 
-``TraceBuilder`` collects complete-events ("ph":"X") — one span per tick
-section (gate -> batched hop -> decision -> riders -> health/learn jobs)
-— with wall-clock duration and analytical-energy attributes, and writes
-the Chrome trace-event JSON that both ``chrome://tracing`` and
-https://ui.perfetto.dev load directly.
+``span(name, **args)`` enters ``jax.profiler.TraceAnnotation("serving." +
+name, **args)``.  With no profiler session active it records nothing and
+costs well under a microsecond; under ``jax.profiler.trace(dir)`` each
+span lands on its host thread's line of the same trace that holds the
+device's ops, so one timeline shows a tick's host phases next to the
+programs they dispatched and the idle gaps between them.  Spans are always
+on: a profiled server runs the very program that is served.
 
-Timestamps are microseconds relative to the earliest event start —
-rebased at export time, since the span that *starts* first (the
-whole-tick span) is recorded last within a tick — so traces are
-deterministic up to wall-clock jitter and diff cleanly.  Spans carry
-arbitrary ``args`` (tick, slots, uJ, cause...), which Perfetto shows in
-the selection panel.
+The serving stack's spans (``docs/OBSERVABILITY.md`` has the catalogue):
+
+* ``serving.step`` (args ``ticks``, ``slots``; ``device`` in a sharded
+  pool) around ``StreamServer.step()`` / ``step_block()``;
+* the compiled block (``repro.serving.compiled``): ``horizon``, ``stage``,
+  ``vad``, ``fate``, ``dispatch``, ``fetch`` (the host waiting on the
+  device) and ``book``;
+* the interpreted tick: ``admit``, ``replay``, ``hop``, ``gate``,
+  ``decide`` and ``riders``;
+* ``serving.fleet_step`` (arg ``pools``) around a sharded router's tick.
+
+Keep arguments to the outer spans: each one is a keyword the caller
+builds even when no profiler listens.
 """
 
 from __future__ import annotations
 
-import json
+import jax
 
-__all__ = ["TraceBuilder"]
+__all__ = ["SPAN_PREFIX", "span"]
+
+SPAN_PREFIX = "serving."
 
 
-class TraceBuilder:
-    def __init__(self, process_name="repro.serving"):
-        # events hold absolute perf_counter seconds in "ts"; to_chrome()
-        # rebases everything onto the earliest start at export time
-        self._events = []
-        self._process_name = process_name
-
-    def __len__(self):
-        return len(self._events)
-
-    def span(self, name, t_start_s, t_end_s, tid=0, **args):
-        """Record a complete span; times are ``time.perf_counter()`` values."""
-        self._events.append({
-            "name": str(name),
-            "ph": "X",
-            "ts": float(t_start_s),
-            "dur": max(0.0, (t_end_s - t_start_s) * 1e6),
-            "pid": 0,
-            "tid": int(tid),
-            "args": args,
-        })
-
-    def counter(self, name, t_s, **values):
-        """Record a counter track sample (Perfetto renders as a graph)."""
-        self._events.append({
-            "name": str(name),
-            "ph": "C",
-            "ts": float(t_s),
-            "pid": 0,
-            "args": values,
-        })
-
-    def instant(self, name, t_s, **args):
-        """Record an instant marker (admission, alarm, swap...)."""
-        self._events.append({
-            "name": str(name),
-            "ph": "i",
-            "ts": float(t_s),
-            "pid": 0,
-            "tid": 0,
-            "s": "p",
-            "args": args,
-        })
-
-    def to_chrome(self):
-        meta = [{
-            "name": "process_name",
-            "ph": "M",
-            "pid": 0,
-            "args": {"name": self._process_name},
-        }]
-        t0 = min((e["ts"] for e in self._events), default=0.0)
-        events = [dict(e, ts=(e["ts"] - t0) * 1e6) for e in self._events]
-        return {"traceEvents": meta + events,
-                "displayTimeUnit": "ms"}
-
-    def dump(self, path):
-        """Write Chrome trace-event JSON; returns the span/event count."""
-        with open(path, "w") as f:
-            json.dump(self.to_chrome(), f, indent=1)
-            f.write("\n")
-        return len(self._events)
+def span(name: str, **args):
+    """A profiler span ``serving.<name>`` (a context manager)."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **args)

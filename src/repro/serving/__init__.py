@@ -56,7 +56,7 @@ from repro.core.faults import FaultConfig, FaultModel
 from repro.core.sa_noise import SANoiseField
 from repro.serving.compiled import CompiledTick, CompiledTickConfig
 from repro.obs import (FlightRecorder, LaunchAuditError, LaunchAuditor,
-                       MetricsRegistry, ObsConfig, TraceBuilder)
+                       MetricsRegistry, ObsConfig)
 from repro.serving.customize import (CustomizationResult,
                                      CustomizationSession, CustomizeConfig)
 from repro.serving.health import HealthConfig, HealthMonitor
@@ -85,7 +85,7 @@ __all__ = [
     "HealthConfig", "HealthMonitor", "LaunchAuditError", "LaunchAuditor",
     "MetricsRegistry", "ObsConfig", "SANoiseField", "ShardedStreamServer",
     "StreamServer",
-    "StreamEngine", "StreamGeometry", "StreamState", "TraceBuilder",
+    "StreamEngine", "StreamGeometry", "StreamState",
     "VADConfig", "VADState", "decision_init",
     "decision_step", "frame_energy_db", "gated_step", "gated_window_step",
     "hop_alignment", "hop_sa_noise_fields", "make_stream_geometry",

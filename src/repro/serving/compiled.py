@@ -30,9 +30,11 @@ returns 0 and ``step()`` falls back to the interpreted tick):
   dynamic-hop retargets (the horizon is clipped so a retarget can only
   land exactly at the block end, where the Python path applies it),
 * session traffic: active customization sessions, health canaries,
-  profile-store sweeps, ``force_compute``/internal streams,
-* per-tick Chrome tracing (``obs.trace``) — span timing is host-side by
-  nature.
+  profile-store sweeps, ``force_compute``/internal streams.
+
+A profiled server serves through the block too: its host phases are
+``repro.obs.span``s (``serving.horizon``, ``.stage``, ``.vad``, ``.fate``,
+``.dispatch``, ``.fetch``, ``.book``) on the profiler's clock.
 
 **Wake-margin replay without dynamic shapes.**  The scan cannot defer a
 variable number of hops, so the block is scanned over a per-slot *hop
@@ -69,6 +71,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import span
 from repro.serving import decision as dec
 from repro.serving import stream as sv
 from repro.serving import vad as vd
@@ -122,10 +125,12 @@ class CompiledTick:
         condition the compiled block does not model exactly falls back —
         the Python tick is always correct, and one interpreted tick
         usually clears the condition (admission wave, resize, shed)."""
+        with span("horizon"):
+            return self._horizon(max_ticks)
+
+    def _horizon(self, max_ticks: int) -> int:
         srv = self._srv
         if max_ticks < 1 or not srv.streaming:
-            return 0
-        if srv.trace is not None:
             return 0
         if (srv._health is not None or srv._profiles is not None
                 or srv._cust is not None):
@@ -265,23 +270,187 @@ class CompiledTick:
         if srv._audit is not None:
             srv._audit.begin_tick(tick0)
 
-        # fault model in lockstep: per-tick chip delta sequence (the
-        # Python tick refreshes the rider operand at each tick start)
-        chip_seq: Optional[list] = None
-        if srv._faults is not None:
-            chip_seq = []
-            for _ in range(k):
-                srv._faults.tick()
-                if srv._faults.pop_dirty():
-                    srv._refresh_chip_delta()
-                chip_seq.append(srv._chip_delta_j)
-            if all(c is chip_seq[0] for c in chip_seq):
-                chip_seq = None       # constant across the block: the
-                #                       current rider operand covers it
+        with span("stage"):
+            chip_seq = self._tick_faults(k)
+            ready, audio, recs, seq, p0, nready, rem0 = self._stage(k)
 
-        # stage the block's ready hops (the Python tick consumes one hop
-        # per ready slot per tick; readiness is a per-slot prefix since
-        # nothing is submitted mid-block)
+        with srv._region("compiled"):
+            # dispatch 1: the VAD block (no IMC kernels) — flags come
+            # back to the host so the fate simulation below is the one
+            # source of truth for masks, events and counters
+            with span("vad"):
+                if srv.vcfg is not None:
+                    kp = _pow2(k)
+                    audio_p = np.zeros((kp, n, hop), np.float32)
+                    audio_p[:k] = audio
+                    ready_p = np.zeros((kp, n), bool)
+                    ready_p[:k] = ready
+                    srv._vstate, flags = self._vad_fn()(
+                        srv._vstate, jnp.asarray(audio_p),
+                        jnp.asarray(ready_p))
+                    speech = np.asarray(flags)[:k] & ready
+                else:
+                    speech = ready.copy()
+
+            with span("fate"):
+                sched, comp_tick, jmax, ops = self._fate(
+                    k, m, ready, speech, recs, seq, p0, nready, chip_seq)
+
+            trig = kwd = sc = None
+            if jmax > 0:
+                key, (audio_tl, cm_p, fm_p), riders = ops
+                with span("dispatch"):
+                    fn = self._main_fn(srv._mult, *key)
+                    srv._state, srv._dstate, outs = fn(
+                        srv._state, srv._dstate, jnp.asarray(audio_tl),
+                        jnp.asarray(cm_p),
+                        None if fm_p is None else jnp.asarray(fm_p),
+                        *riders)
+            with span("fetch"):
+                if jmax > 0:
+                    trig, kwd, sc = jax.device_get(outs)   # one transfer
+                jax.block_until_ready((srv._state, srv._dstate))
+        dt = time.perf_counter() - t_start
+        with span("book"):
+            srv._hop_wall_s += dt
+            if comp_tick:
+                per_slot = {}
+                for (s, _j) in comp_tick:
+                    per_slot[s] = per_slot.get(s, 0) + 1
+                for s, cnt in per_slot.items():
+                    recs[s].wall_s += dt * cnt / len(comp_tick)
+
+            # host replay of the per-tick bookkeeping, in tick order — the
+            # exact side-effect sequence of k Python ticks
+            events_all: List[dict] = []
+            for t in range(k):
+                tick = tick0 + t
+                self._sim_autoscale()
+                tk = sched[t]
+                tick_events: List[dict] = []
+                for s in sorted(recs):
+                    if not ready[t, s]:
+                        continue
+                    rec = recs[s]
+                    if speech[t, s]:
+                        rec.silent_run = 0
+                        if rec.pending:
+                            rec.pending = []   # drained by the wake replay
+                    else:
+                        rec.silent_run += 1
+                        rec.pending.append(audio[t, s])
+                        if len(rec.pending) > m:
+                            aged = rec.pending.pop(0)
+                            rec.recent = np.concatenate(
+                                [rec.recent, aged])[-window:]
+                            rec.consumed += hop
+                            rec.gated_hops += 1
+                            srv._gated_hops += 1
+                for s, js in tk["replays"]:
+                    rec = recs[s]
+                    srv._replay_calls += 1
+                    for j in js:
+                        srv._decisions += 1
+                        srv._speech_hops += 1
+                        rec.recent = np.concatenate(
+                            [rec.recent, seq[s][j]])[-window:]
+                        rec.consumed += hop
+                        rec.hops += 1
+                        ev = {"stream": rec.stream_id, "hop": rec.hops - 1,
+                              "keyword": int(kwd[j, s]),
+                              "score": float(sc[j, s]),
+                              "trigger": bool(trig[j, s])}
+                        tick_events.append(ev)
+                        if ev["trigger"]:
+                            rec.triggers.append(ev)
+                if tk["regular"]:
+                    srv._hop_calls += 1
+                    for s, j in tk["regular"]:
+                        rec = recs[s]
+                        srv._speech_hops += 1
+                        rec.hops += 1
+                        rec.consumed += hop
+                        rec.recent = np.concatenate(
+                            [rec.recent, seq[s][j]])[-window:]
+                    srv._decisions += len(tk["regular"])
+                    for s, j in tk["regular"]:
+                        rec = recs[s]
+                        ev = {"stream": rec.stream_id, "hop": rec.hops - 1,
+                              "keyword": int(kwd[j, s]),
+                              "score": float(sc[j, s]),
+                              "trigger": bool(trig[j, s])}
+                        tick_events.append(ev)
+                        if ev["trigger"]:
+                            rec.triggers.append(ev)
+                if tk["fills"]:
+                    srv._gate_calls += 1
+
+                # retire drained finished streams (evaluated on the VIRTUAL
+                # buffer length: staging consumed the block's hops up front)
+                for s, rec in enumerate(list(srv._slots)):
+                    if rec is None or not rec.finished:
+                        continue
+                    if rec.initialized and s in recs:
+                        remaining = (rem0[s]
+                                     + max(nready[s] - (t + 1), 0) * hop)
+                    else:
+                        remaining = len(rec.buf)
+                    if remaining < (hop if rec.initialized else window):
+                        srv._free_slot(rec)
+                srv._steps += 1
+                silent_t = (bool(ready[t].any())
+                            and not bool((speech[t] & ready[t]).any()))
+                srv._retarget_hop(tick_events, woke=bool(tk["replays"]),
+                                  silent=silent_t)
+                if srv.hcfg is not None and t < k - 1:
+                    assert srv._mult == mult0, \
+                        "hop retarget fired inside a compiled block"
+                n_replay_hops = sum(len(js) for _, js in tk["replays"])
+                computed = n_replay_hops + len(tk["regular"])
+                gated_n = len(tk["fills"])
+                if srv._rec is not None and (computed or gated_n
+                                             or tick_events):
+                    uj = srv._tick_uj(computed, gated_n)
+                    srv._rec.record(tick, "tick", init=0, computed=computed,
+                                    gated=gated_n, replays=len(tk["replays"]),
+                                    decisions=len(tick_events),
+                                    uj=round(uj, 4))
+                    srv._metrics.observe("serving.tick_uj", uj)
+                events_all.extend(tick_events)
+
+            if srv._audit is not None:
+                srv._audit.end_tick()
+                for t in range(1, k):
+                    srv._audit.begin_tick(tick0 + t)
+                    srv._audit.end_tick()
+            srv._compiled_blocks += 1
+            srv._compiled_ticks += k
+        return events_all
+
+    def _tick_faults(self, k: int) -> Optional[list]:
+        """Tick the fault model in lockstep: the per-tick chip delta
+        sequence (the Python tick refreshes the rider operand at each tick
+        start), or None when it is constant across the block (the current
+        rider operand covers it)."""
+        srv = self._srv
+        if srv._faults is None:
+            return None
+        chip_seq = []
+        for _ in range(k):
+            srv._faults.tick()
+            if srv._faults.pop_dirty():
+                srv._refresh_chip_delta()
+            chip_seq.append(srv._chip_delta_j)
+        if all(c is chip_seq[0] for c in chip_seq):
+            return None
+        return chip_seq
+
+    def _stage(self, k: int):
+        """Stage the block's ready hops (the Python tick consumes one hop
+        per ready slot per tick; readiness is a per-slot prefix since
+        nothing is submitted mid-block)."""
+        srv = self._srv
+        hop, n = srv.geom.hop, srv.slots
         ready = np.zeros((k, n), bool)
         audio = np.zeros((k, n, hop), np.float32)
         recs: Dict[int, object] = {}
@@ -304,233 +473,105 @@ class CompiledTick:
             seq[s] = list(rec.pending) + list(chunks)
             ready[:rs, s] = True
             audio[:rs, s] = chunks
+        return ready, audio, recs, seq, p0, nready, rem0
 
-        with srv._region("compiled"):
-            # dispatch 1: the VAD block (no IMC kernels) — flags come
-            # back to the host so the fate simulation below is the one
-            # source of truth for masks, events and counters
-            if srv.vcfg is not None:
-                kp = _pow2(k)
-                audio_p = np.zeros((kp, n, hop), np.float32)
-                audio_p[:k] = audio
-                ready_p = np.zeros((kp, n), bool)
-                ready_p[:k] = ready
-                srv._vstate, flags = self._vad_fn()(
-                    srv._vstate, jnp.asarray(audio_p),
-                    jnp.asarray(ready_p))
-                speech = np.asarray(flags)[:k] & ready
-            else:
-                speech = ready.copy()
-
-            # host fate simulation: replicate the Python tick's
-            # classification exactly — per tick, per slot (slot order):
-            # speech wakes + replays any deferred hops, silence defers
-            # the hop and ages the oldest out of the wake margin
-            pend = {s: list(range(p0[s])) for s in recs}
-            sched = []
-            for t in range(k):
-                tk = {"replays": [], "regular": [], "fills": []}
-                for s in sorted(recs):
-                    if not ready[t, s]:
-                        continue
-                    j = p0[s] + t
-                    if speech[t, s]:
-                        if pend[s]:
-                            tk["replays"].append((s, pend[s] + [j]))
-                            pend[s] = []
-                        else:
-                            tk["regular"].append((s, j))
-                    else:
-                        pend[s].append(j)
-                        if len(pend[s]) > m:
-                            tk["fills"].append((s, pend[s].pop(0)))
-                sched.append(tk)
-
-            # masks over the hop-timeline index j (per slot, hop j is
-            # its j-th hop since block start: deferred-entering hops
-            # first, then the freshly staged ones)
-            jcap = max((p0[s] + nready[s] for s in recs), default=0)
-            cm = np.zeros((max(jcap, 1), n), bool)
-            fm = np.zeros((max(jcap, 1), n), bool)
-            comp_tick: Dict[tuple, int] = {}
-            jmax = 0
-            for t, tk in enumerate(sched):
-                for s, js in tk["replays"]:
-                    for j in js:
-                        cm[j, s] = True
-                        comp_tick[(s, j)] = t
-                        jmax = max(jmax, j + 1)
-                for s, j in tk["regular"]:
-                    cm[j, s] = True
-                    comp_tick[(s, j)] = t
-                    jmax = max(jmax, j + 1)
-                for s, j in tk["fills"]:
-                    fm[j, s] = True
-                    jmax = max(jmax, j + 1)
-
-            trig = kwd = sc = None
-            if jmax > 0:
-                jp = _pow2(jmax)
-                audio_tl = np.zeros((jp, n, hop), np.float32)
-                for s in recs:
-                    for j, ch in enumerate(seq[s][:jmax]):
-                        audio_tl[j, s] = ch
-                cm_p = np.zeros((jp, n), bool)
-                cm_p[:jmax] = cm[:jmax]
-                fm_p = np.zeros((jp, n), bool)
-                fm_p[:jmax] = fm[:jmax]
-
-                cust = srv._cust_on
-                per_tick_chip = chip_seq is not None
-                gated = srv.vcfg is not None
-                delta = hw_ = hb_ = chip = fills = None
-                if cust:
-                    if per_tick_chip:
-                        # stage per-scan-step chip deltas mapped by each
-                        # hop's COMPUTE tick (a wake replay reads its
-                        # wake tick's delta, like the Python replay call)
-                        delta = srv._slot_delta
-                        hw_, hb_ = srv._slot_head_w, srv._slot_head_b
-                        chip = {
-                            name: np.zeros((jp, n, srv.cfg.channels[
-                                int(name[4:])]), np.float32)
-                            for name in srv.cfg.imc_layer_names()}
-                        for (s, j), t in comp_tick.items():
-                            d = chip_seq[t]
-                            if d is not None:
-                                for name in chip:
-                                    chip[name][j, s] = np.asarray(d[name])
-                        chip = {name: jnp.asarray(v)
-                                for name, v in chip.items()}
-                    else:
-                        delta, hw_, hb_ = srv._slot_custom_args()
-                if gated:
-                    fills = (srv._slot_fills
-                             if cust and srv._slot_fills is not None
-                             else srv._fills)
-
-                fn = self._main_fn(srv._mult, cust, per_tick_chip, gated)
-                srv._state, srv._dstate, outs = fn(
-                    srv._state, srv._dstate, jnp.asarray(audio_tl),
-                    jnp.asarray(cm_p), jnp.asarray(fm_p) if gated else None,
-                    delta, hw_, hb_, chip, fills)
-                trig, kwd, sc = jax.device_get(outs)   # one transfer
-            jax.block_until_ready((srv._state, srv._dstate))
-        dt = time.perf_counter() - t_start
-        srv._hop_wall_s += dt
-        if comp_tick:
-            per_slot = {}
-            for (s, _j) in comp_tick:
-                per_slot[s] = per_slot.get(s, 0) + 1
-            for s, cnt in per_slot.items():
-                recs[s].wall_s += dt * cnt / len(comp_tick)
-
-        # host replay of the per-tick bookkeeping, in tick order — the
-        # exact side-effect sequence of k Python ticks
-        events_all: List[dict] = []
+    def _fate(self, k, m, ready, speech, recs, seq, p0, nready, chip_seq):
+        """The host fate simulation, its masks and the main block's
+        operands.  Returns (per-tick schedule, {(slot, j): compute tick},
+        timeline length, operands), the operands (None when nothing is
+        computed or filled) being the rest of the ``_main_fn`` cache key,
+        the host arrays (audio timeline, compute mask, fill mask or None)
+        and the rider operands."""
+        srv = self._srv
+        hop, n = srv.geom.hop, srv.slots
+        # replicate the Python tick's classification exactly — per tick,
+        # per slot (slot order): speech wakes + replays any deferred hops,
+        # silence defers the hop and ages the oldest out of the wake margin
+        pend = {s: list(range(p0[s])) for s in recs}
+        sched = []
         for t in range(k):
-            tick = tick0 + t
-            self._sim_autoscale()
-            tk = sched[t]
-            tick_events: List[dict] = []
+            tk = {"replays": [], "regular": [], "fills": []}
             for s in sorted(recs):
                 if not ready[t, s]:
                     continue
-                rec = recs[s]
+                j = p0[s] + t
                 if speech[t, s]:
-                    rec.silent_run = 0
-                    if rec.pending:
-                        rec.pending = []   # drained by the wake replay
+                    if pend[s]:
+                        tk["replays"].append((s, pend[s] + [j]))
+                        pend[s] = []
+                    else:
+                        tk["regular"].append((s, j))
                 else:
-                    rec.silent_run += 1
-                    rec.pending.append(audio[t, s])
-                    if len(rec.pending) > m:
-                        aged = rec.pending.pop(0)
-                        rec.recent = np.concatenate(
-                            [rec.recent, aged])[-window:]
-                        rec.consumed += hop
-                        rec.gated_hops += 1
-                        srv._gated_hops += 1
+                    pend[s].append(j)
+                    if len(pend[s]) > m:
+                        tk["fills"].append((s, pend[s].pop(0)))
+            sched.append(tk)
+
+        # masks over the hop-timeline index j (per slot, hop j is its j-th
+        # hop since block start: deferred-entering hops first, then the
+        # freshly staged ones)
+        jcap = max((p0[s] + nready[s] for s in recs), default=0)
+        cm = np.zeros((max(jcap, 1), n), bool)
+        fm = np.zeros((max(jcap, 1), n), bool)
+        comp_tick: Dict[tuple, int] = {}
+        jmax = 0
+        for t, tk in enumerate(sched):
             for s, js in tk["replays"]:
-                rec = recs[s]
-                srv._replay_calls += 1
                 for j in js:
-                    srv._decisions += 1
-                    srv._speech_hops += 1
-                    rec.recent = np.concatenate(
-                        [rec.recent, seq[s][j]])[-window:]
-                    rec.consumed += hop
-                    rec.hops += 1
-                    ev = {"stream": rec.stream_id, "hop": rec.hops - 1,
-                          "keyword": int(kwd[j, s]),
-                          "score": float(sc[j, s]),
-                          "trigger": bool(trig[j, s])}
-                    tick_events.append(ev)
-                    if ev["trigger"]:
-                        rec.triggers.append(ev)
-            if tk["regular"]:
-                srv._hop_calls += 1
-                for s, j in tk["regular"]:
-                    rec = recs[s]
-                    srv._speech_hops += 1
-                    rec.hops += 1
-                    rec.consumed += hop
-                    rec.recent = np.concatenate(
-                        [rec.recent, seq[s][j]])[-window:]
-                srv._decisions += len(tk["regular"])
-                for s, j in tk["regular"]:
-                    rec = recs[s]
-                    ev = {"stream": rec.stream_id, "hop": rec.hops - 1,
-                          "keyword": int(kwd[j, s]),
-                          "score": float(sc[j, s]),
-                          "trigger": bool(trig[j, s])}
-                    tick_events.append(ev)
-                    if ev["trigger"]:
-                        rec.triggers.append(ev)
-            if tk["fills"]:
-                srv._gate_calls += 1
+                    cm[j, s] = True
+                    comp_tick[(s, j)] = t
+                    jmax = max(jmax, j + 1)
+            for s, j in tk["regular"]:
+                cm[j, s] = True
+                comp_tick[(s, j)] = t
+                jmax = max(jmax, j + 1)
+            for s, j in tk["fills"]:
+                fm[j, s] = True
+                jmax = max(jmax, j + 1)
+        if jmax == 0:
+            return sched, comp_tick, 0, None
 
-            # retire drained finished streams (evaluated on the VIRTUAL
-            # buffer length: staging consumed the block's hops up front)
-            for s, rec in enumerate(list(srv._slots)):
-                if rec is None or not rec.finished:
-                    continue
-                if rec.initialized and s in recs:
-                    remaining = (rem0[s]
-                                 + max(nready[s] - (t + 1), 0) * hop)
-                else:
-                    remaining = len(rec.buf)
-                if remaining < (hop if rec.initialized else window):
-                    srv._free_slot(rec)
-            srv._steps += 1
-            silent_t = (bool(ready[t].any())
-                        and not bool((speech[t] & ready[t]).any()))
-            srv._retarget_hop(tick_events, woke=bool(tk["replays"]),
-                              silent=silent_t)
-            if srv.hcfg is not None and t < k - 1:
-                assert srv._mult == mult0, \
-                    "hop retarget fired inside a compiled block"
-            n_replay_hops = sum(len(js) for _, js in tk["replays"])
-            computed = n_replay_hops + len(tk["regular"])
-            gated_n = len(tk["fills"])
-            if srv._rec is not None and (computed or gated_n
-                                         or tick_events):
-                uj = srv._tick_uj(computed, gated_n)
-                srv._rec.record(tick, "tick", init=0, computed=computed,
-                                gated=gated_n, replays=len(tk["replays"]),
-                                decisions=len(tick_events),
-                                uj=round(uj, 4))
-                srv._metrics.observe("serving.tick_uj", uj)
-            events_all.extend(tick_events)
+        jp = _pow2(jmax)
+        audio_tl = np.zeros((jp, n, hop), np.float32)
+        for s in recs:
+            for j, ch in enumerate(seq[s][:jmax]):
+                audio_tl[j, s] = ch
+        cm_p = np.zeros((jp, n), bool)
+        cm_p[:jmax] = cm[:jmax]
+        fm_p = np.zeros((jp, n), bool)
+        fm_p[:jmax] = fm[:jmax]
 
-        if srv._audit is not None:
-            srv._audit.end_tick()
-            for t in range(1, k):
-                srv._audit.begin_tick(tick0 + t)
-                srv._audit.end_tick()
-        srv._compiled_blocks += 1
-        srv._compiled_ticks += k
-        return events_all
+        cust = srv._cust_on
+        per_tick_chip = chip_seq is not None
+        gated = srv.vcfg is not None
+        delta = hw_ = hb_ = chip = fills = None
+        if cust:
+            if per_tick_chip:
+                # stage per-scan-step chip deltas mapped by each hop's
+                # COMPUTE tick (a wake replay reads its wake tick's delta,
+                # like the Python replay call)
+                delta = srv._slot_delta
+                hw_, hb_ = srv._slot_head_w, srv._slot_head_b
+                chip = {
+                    name: np.zeros((jp, n, srv.cfg.channels[
+                        int(name[4:])]), np.float32)
+                    for name in srv.cfg.imc_layer_names()}
+                for (s, j), t in comp_tick.items():
+                    d = chip_seq[t]
+                    if d is not None:
+                        for name in chip:
+                            chip[name][j, s] = np.asarray(d[name])
+                chip = {name: jnp.asarray(v) for name, v in chip.items()}
+            else:
+                delta, hw_, hb_ = srv._slot_custom_args()
+        if gated:
+            fills = (srv._slot_fills
+                     if cust and srv._slot_fills is not None
+                     else srv._fills)
+        ops = ((cust, per_tick_chip, gated),
+               (audio_tl, cm_p, fm_p if gated else None),
+               (delta, hw_, hb_, chip, fills))
+        return sched, comp_tick, jmax, ops
 
     def _sim_autoscale(self) -> None:
         """Replay ``_autoscale``'s counter bookkeeping for one in-block

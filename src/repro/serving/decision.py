@@ -68,6 +68,7 @@ def decision_init(n: int, num_classes: int,
         last_kw=jnp.zeros((n,), jnp.int32))
 
 
+@jax.named_scope("decision")
 def decision_step(dcfg: DecisionConfig, state: DecisionState,
                   logits: jax.Array,
                   active: Optional[jax.Array] = None):

@@ -129,7 +129,7 @@ import numpy as np
 from repro.core import energy
 from repro.models import kws
 from repro.obs import (FlightRecorder, LaunchAuditor, MetricsRegistry,
-                       ObsConfig, TraceBuilder, counter_property)
+                       ObsConfig, compiles, counter_property, span)
 from repro.serving import decision as dec
 from repro.serving import stream as sv
 from repro.serving import vad as vd
@@ -391,7 +391,11 @@ class StreamServer:
                                      batch_init=batch_init,
                                      device=device_label)
                        if self.obs.audit != "off" else None)
-        self.trace = TraceBuilder() if self.obs.trace else None
+        # serving.step's span arguments beyond ticks/slots (a sharded
+        # pool names its device)
+        self._span_args = ({} if device_label is None
+                           else {"device": device_label})
+        compiles.install()
         self._uj_consts: Dict[int, tuple] = {}   # mult -> (speech, gated)
         self.cfg = cfg
         self.streaming = streaming
@@ -1296,9 +1300,6 @@ class StreamServer:
             dt = time.perf_counter() - t0
             self._hop_wall_s += dt
             self._init_calls += 1
-            if self.trace is not None:
-                self.trace.span("init", t0, t0 + dt, tick=self._steps,
-                                slots=len(todo))
             for s, rec in todo:
                 _book(rec, s, windows[s], dt / len(todo))
                 init_logits[s] = np.asarray(logits[s])
@@ -1337,9 +1338,15 @@ class StreamServer:
         sheds, resizes, session/health traffic — falls back to the
         interpreted path; both produce bit-identical events, state and
         counters (dispatch accounting aside)."""
-        if self._compiled is not None and self._compiled.horizon(1) == 1:
-            return self._compiled.run(1)
-        return self._step_python()
+        c0 = compiles.tally()
+        with span("step", ticks=1, slots=self.slots, **self._span_args):
+            if (self._compiled is not None
+                    and self._compiled.horizon(1) == 1):
+                events = self._compiled.run(1)
+            else:
+                events = self._step_python()
+        self._book_compiles(c0)
+        return events
 
     def step_block(self, max_ticks: Optional[int] = None) -> List[dict]:
         """Serve up to ``max_ticks`` steady-state ticks in ONE compiled
@@ -1351,14 +1358,26 @@ class StreamServer:
         structural boundary (``CompiledTick.horizon``).  A tick the
         compiled path cannot model at all runs interpreted.  Without
         ``compiled=`` this is exactly one interpreted ``step()``."""
-        if self._compiled is None:
-            return self._step_python()
-        cap = self._compiled.cfg.block
-        k = self._compiled.horizon(cap if max_ticks is None
-                                   else min(max_ticks, cap))
-        if k < 1:
-            return self._step_python()
-        return self._compiled.run(k)
+        c0 = compiles.tally()
+        with span("step", slots=self.slots, **self._span_args) as sp:
+            k = 0
+            if self._compiled is not None:
+                cap = self._compiled.cfg.block
+                k = self._compiled.horizon(cap if max_ticks is None
+                                           else min(max_ticks, cap))
+            sp.set_metadata(ticks=max(k, 1))
+            events = (self._compiled.run(k) if k >= 1
+                      else self._step_python())
+        self._book_compiles(c0)
+        return events
+
+    def _book_compiles(self, before) -> None:
+        """Book the process's jit traces / compiles / cache loads since
+        ``before`` (a ``compiles.tally()``) as this step's
+        ``serving.compiles{kind}``."""
+        for kind, a, b in zip(compiles.KINDS, compiles.tally(), before):
+            if a != b:
+                self._metrics.inc("serving.compiles", a - b, kind=kind)
 
     def _step_python(self) -> List[dict]:
         """One interpreted scheduler tick: SLO shedding, autoscaling,
@@ -1367,7 +1386,6 @@ class StreamServer:
         gated slot, then the batched decision update.  This is the
         reference semantics the compiled fast path is proven against."""
         tick = self._steps
-        t_tick = time.perf_counter()
         if self._audit is not None:
             self._audit.begin_tick(tick)
         self._check_profiles()
@@ -1380,7 +1398,8 @@ class StreamServer:
         bundle = self._bundle(self._mult)
         hop = self.geom.hop
         window = self.geom.window
-        init_mask, init_logits = self._admit_ready()
+        with span("admit"):
+            init_mask, init_logits = self._admit_ready()
 
         ready = np.zeros((self.slots,), bool)
         audio = np.zeros((self.slots, hop), np.float32)
@@ -1451,28 +1470,27 @@ class StreamServer:
             a = np.zeros((self.slots, n * hop), np.float32)
             a[s] = np.concatenate(chunks)
             t0 = time.perf_counter()
-            with self._region("replay"):
-                if self._cust_on:
-                    fn = self._replay_fn(bundle, n, cust=True)
-                    lg, self._state = fn(self._state, jnp.asarray(a),
-                                         mask_j, *self._slot_custom_args())
-                else:
-                    fn = self._replay_fn(bundle, n, cust=False)
-                    lg, self._state = fn(self._state, jnp.asarray(a),
-                                         mask_j)
-            self._replay_calls += 1
-            outs = []
-            for j in range(n):
-                self._dstate, out = self._decide(self._dstate, lg[:, j],
-                                                 mask_j)
-                outs.append(out)
-            outs[-1].score.block_until_ready()
+            with span("replay"):
+                with self._region("replay"):
+                    if self._cust_on:
+                        fn = self._replay_fn(bundle, n, cust=True)
+                        lg, self._state = fn(self._state, jnp.asarray(a),
+                                             mask_j,
+                                             *self._slot_custom_args())
+                    else:
+                        fn = self._replay_fn(bundle, n, cust=False)
+                        lg, self._state = fn(self._state, jnp.asarray(a),
+                                             mask_j)
+                self._replay_calls += 1
+                outs = []
+                for j in range(n):
+                    self._dstate, out = self._decide(self._dstate,
+                                                     lg[:, j], mask_j)
+                    outs.append(out)
+                outs[-1].score.block_until_ready()
             dt = time.perf_counter() - t0
             rec.wall_s += dt
             self._hop_wall_s += dt
-            if self.trace is not None:
-                self.trace.span("replay", t0, t0 + dt, tick=tick,
-                                stream=rec.stream_id, hops=n)
             for j, (ch, out) in enumerate(zip(chunks, outs)):
                 self._decisions += 1
                 self._speech_hops += 1
@@ -1491,7 +1509,7 @@ class StreamServer:
         if compute_mask.any():
             t0 = time.perf_counter()
             mask_j = jnp.asarray(compute_mask)
-            with self._region("hop"):
+            with span("hop"), self._region("hop"):
                 if self._cust_on:
                     hop_logits, self._state = bundle["hop_cust"](
                         self._state, jnp.asarray(audio), mask_j,
@@ -1504,9 +1522,6 @@ class StreamServer:
             self._hop_wall_s += dt
             self._hop_calls += 1
             n_active = int(compute_mask.sum())
-            if self.trace is not None:
-                self.trace.span("hop", t0, t0 + dt, tick=tick,
-                                slots=n_active)
             for s, rec in enumerate(self._slots):
                 if compute_mask[s]:
                     if rec.internal:
@@ -1523,7 +1538,7 @@ class StreamServer:
 
         if fill_mask.any():
             t0 = time.perf_counter()
-            with self._region("gate"):
+            with span("gate"), self._region("gate"):
                 if self._cust_on and self._slot_fills is not None:
                     self._state = bundle["gate_cust"](
                         self._state, jnp.asarray(fill_mask),
@@ -1535,26 +1550,19 @@ class StreamServer:
             dt = time.perf_counter() - t0
             self._hop_wall_s += dt
             self._gate_calls += 1
-            if self.trace is not None:
-                self.trace.span("gate", t0, t0 + dt, tick=tick,
-                                slots=int(fill_mask.sum()))
 
         internal = np.asarray([rec is not None and rec.internal
                                for rec in self._slots])
         decide_mask = (init_mask | compute_mask) & ~internal
         if bool(decide_mask.any()):
-            t0 = time.perf_counter()
-            self._dstate, out = self._decide(self._dstate,
-                                             jnp.asarray(logits),
-                                             jnp.asarray(decide_mask))
+            with span("decide"):
+                self._dstate, out = self._decide(self._dstate,
+                                                 jnp.asarray(logits),
+                                                 jnp.asarray(decide_mask))
+                trig = np.asarray(out.trigger)
+                kwd = np.asarray(out.keyword)
+                score = np.asarray(out.score)
             self._decisions += int(decide_mask.sum())
-            if self.trace is not None:
-                out.score.block_until_ready()
-                self.trace.span("decide", t0, time.perf_counter(),
-                                tick=tick, slots=int(decide_mask.sum()))
-            trig = np.asarray(out.trigger)
-            kwd = np.asarray(out.keyword)
-            score = np.asarray(out.score)
             for s, rec in enumerate(self._slots):
                 if rec is None or not decide_mask[s]:
                     continue
@@ -1566,56 +1574,49 @@ class StreamServer:
                     rec.triggers.append(ev)
 
         # feature captures must see the post-hop states before slots retire
-        t_riders = time.perf_counter() if self.trace is not None else 0.0
-        if self._cust is not None:
-            self._cust.on_step(self)
-        if self._health is not None:
-            self._health.on_step(self)          # canary carry/ring capture
+        with span("riders"):
+            if self._cust is not None:
+                self._cust.on_step(self)
+            if self._health is not None:
+                self._health.on_step(self)      # canary carry/ring capture
 
-        # decisions emitted while the chip is not healthy are flagged so
-        # downstream consumers can discount (or re-request) them
-        if self._health is not None:
-            degraded = self._health.state != "healthy"
-            for ev in events:
-                ev["degraded"] = degraded
+            # decisions emitted while the chip is not healthy are flagged
+            # so downstream consumers can discount (or re-request) them
+            if self._health is not None:
+                degraded = self._health.state != "healthy"
+                for ev in events:
+                    ev["degraded"] = degraded
 
-        # retire drained finished streams
-        for rec in list(self._slots):
-            if (rec is not None and rec.finished
-                    and len(rec.buf) < (hop if rec.initialized
-                                        else window)):
-                self._free_slot(rec)
-        self._steps += 1
-        self._retarget_hop(events, woke=bool(replays), silent=silent_tick)
-        # background learning jobs: calibration layers, feature-replay
-        # spawns, bounded fine-tune epochs, hot swaps
-        if self._cust is not None:
-            self._cust.tick(self)
-        # health background work: canary spawns + tick-resumable
-        # recompensation (calibration layers, heal hot-swap)
-        if self._health is not None:
-            self._health.tick(self)
+            # retire drained finished streams
+            for rec in list(self._slots):
+                if (rec is not None and rec.finished
+                        and len(rec.buf) < (hop if rec.initialized
+                                            else window)):
+                    self._free_slot(rec)
+            self._steps += 1
+            self._retarget_hop(events, woke=bool(replays),
+                               silent=silent_tick)
+            # background learning jobs: calibration layers, feature-replay
+            # spawns, bounded fine-tune epochs, hot swaps
+            if self._cust is not None:
+                self._cust.tick(self)
+            # health background work: canary spawns + tick-resumable
+            # recompensation (calibration layers, heal hot-swap)
+            if self._health is not None:
+                self._health.tick(self)
 
-        # -- per-tick telemetry (composition, analytical uJ, spans) --------
-        n_replay_hops = sum(len(chunks) for _, chunks in replays)
-        computed = (int(init_mask.sum()) + int(compute_mask.sum())
-                    + n_replay_hops)
-        gated = int(fill_mask.sum())
-        if self._rec is not None or self.trace is not None:
-            uj = self._tick_uj(computed, gated)
+            # -- per-tick telemetry (composition, analytical uJ) ----------
+            n_replay_hops = sum(len(chunks) for _, chunks in replays)
+            computed = (int(init_mask.sum()) + int(compute_mask.sum())
+                        + n_replay_hops)
+            gated = int(fill_mask.sum())
             if self._rec is not None and (computed or gated or events):
-                self._rec.record(tick, "tick",
-                                 init=int(init_mask.sum()),
+                uj = self._tick_uj(computed, gated)
+                self._rec.record(tick, "tick", init=int(init_mask.sum()),
                                  computed=computed, gated=gated,
-                                 replays=len(replays),
-                                 decisions=len(events), uj=round(uj, 4))
+                                 replays=len(replays), decisions=len(events),
+                                 uj=round(uj, 4))
                 self._metrics.observe("serving.tick_uj", uj)
-            if self.trace is not None:
-                now = time.perf_counter()
-                self.trace.span("riders", t_riders, now, tick=tick)
-                self.trace.span("tick", t_tick, now, tick=tick,
-                                computed=computed, gated=gated,
-                                decisions=len(events), uj=round(uj, 4))
         if self._audit is not None:
             self._audit.end_tick()
         return events

@@ -61,6 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import span
 from repro.serving.scheduler import StreamServer
 from repro.sharding.placement import (PlacementConfig, PlacementPolicy,
                                       PoolLoad)
@@ -249,13 +250,14 @@ class ShardedStreamServer:
         """One fleet tick: every pool steps exactly once (sequentially by
         default, one thread per device with ``parallel=True``).  Events
         are returned in device order, each tagged with its ``device``."""
-        if self._pool_exec is not None:
-            futs = [self._pool_exec.submit(self._tick_pool, d)
-                    for d in range(self.n_devices)]
-            events = [ev for f in futs for ev in f.result()]
-        else:
-            events = [ev for d in range(self.n_devices)
-                      for ev in self._tick_pool(d)]
+        with span("fleet_step", pools=self.n_devices):
+            if self._pool_exec is not None:
+                futs = [self._pool_exec.submit(self._tick_pool, d)
+                        for d in range(self.n_devices)]
+                events = [ev for f in futs for ev in f.result()]
+            else:
+                events = [ev for d in range(self.n_devices)
+                          for ev in self._tick_pool(d)]
         self._steps += 1
         return events
 
@@ -269,13 +271,15 @@ class ShardedStreamServer:
         because streams never interact across pools.  Events are
         returned in device order, tagged with their ``device``.  Pools
         without ``compiled=`` just run one interpreted tick."""
-        if self._pool_exec is not None:
-            futs = [self._pool_exec.submit(self._block_pool, d, max_ticks)
-                    for d in range(self.n_devices)]
-            events = [ev for f in futs for ev in f.result()]
-        else:
-            events = [ev for d in range(self.n_devices)
-                      for ev in self._block_pool(d, max_ticks)]
+        with span("fleet_step", pools=self.n_devices):
+            if self._pool_exec is not None:
+                futs = [self._pool_exec.submit(self._block_pool, d,
+                                               max_ticks)
+                        for d in range(self.n_devices)]
+                events = [ev for f in futs for ev in f.result()]
+            else:
+                events = [ev for d in range(self.n_devices)
+                          for ev in self._block_pool(d, max_ticks)]
         self._steps += 1
         return events
 

@@ -254,8 +254,9 @@ def hop_sa_noise_fields(keys: jax.Array, hops: jax.Array,
         out, base = {}, 0
         for (i, n_tail, c_out) in specs:
             ks = flat_keys[base:base + n_tail]
-            out[f"conv{i}"] = std * jax.vmap(
-                lambda k: jax.random.normal(k, (c_out,)))(ks)
+            with jax.named_scope(f"conv{i}"):
+                out[f"conv{i}"] = std * jax.vmap(
+                    lambda k: jax.random.normal(k, (c_out,)))(ks)
             base += n_tail
         return out
 
@@ -330,10 +331,11 @@ def _ring_logits(hwp: kws.HWParams, ring: jax.Array,
     slots, so only hot-swapped slots actually diverge).  The per-row
     matvec is the same contraction the shared matmul performs row-wise, so
     a row whose head equals the base head produces the base logits."""
-    if head_w is None:
-        return _gap_fc(hwp, ring)[0]
-    feats = ACT_Q.quantize(jnp.mean(ring, axis=1))
-    return jax.vmap(lambda f, w, b: f @ w + b)(feats, head_w, head_b)
+    with jax.named_scope("head"):
+        if head_w is None:
+            return _gap_fc(hwp, ring)[0]
+        feats = ACT_Q.quantize(jnp.mean(ring, axis=1))
+        return jax.vmap(lambda f, w, b: f @ w + b)(feats, head_w, head_b)
 
 
 def _merge_bias_delta(noise: Optional[jax.Array],
@@ -376,23 +378,24 @@ def stream_init(hw, window: jax.Array, keys: jax.Array,
     h = window[..., None]
     carries = []
     for i in range(cfg.num_conv_layers):
-        noise = off = packed_i = None
-        if i > 0:
-            carries.append(_tail(h, geom.layers[i].carry))
-            lg = geom.layers[i]
-            if sa_noise_std > 0.0:
-                cols = jnp.arange(lg.t_conv)
-                noise = jax.vmap(lambda k: sa_noise_columns(
-                    k, i, cols, cfg.channels[i], sa_noise_std))(keys)
-            if bias_delta is not None:
-                noise = _merge_bias_delta(noise, bias_delta[f"conv{i}"],
-                                          lg.t_conv)
-            if chip_offsets is not None:
-                off = chip_offsets[f"conv{i}"]
-            packed_i = packed[f"conv{i}"] if packed else None
-        h = kws.hw_conv_layer(hwp, i, h, cfg, packed=packed_i,
-                              chip_offset=off, sa_noise=noise,
-                              use_kernel=use_kernel)
+        with jax.named_scope(f"conv{i}"):
+            noise = off = packed_i = None
+            if i > 0:
+                carries.append(_tail(h, geom.layers[i].carry))
+                lg = geom.layers[i]
+                if sa_noise_std > 0.0:
+                    cols = jnp.arange(lg.t_conv)
+                    noise = jax.vmap(lambda k: sa_noise_columns(
+                        k, i, cols, cfg.channels[i], sa_noise_std))(keys)
+                if bias_delta is not None:
+                    noise = _merge_bias_delta(noise, bias_delta[f"conv{i}"],
+                                              lg.t_conv)
+                if chip_offsets is not None:
+                    off = chip_offsets[f"conv{i}"]
+                packed_i = packed[f"conv{i}"] if packed else None
+            h = kws.hw_conv_layer(hwp, i, h, cfg, packed=packed_i,
+                                  chip_offset=off, sa_noise=noise,
+                                  use_kernel=use_kernel)
     logits = _ring_logits(hwp, h, head_w, head_b)
     state = StreamState(audio_carry=_tail(window, geom.layers[0].carry),
                         carries=tuple(carries), ring=h,
@@ -411,9 +414,10 @@ def _stream_advance(hw, state: StreamState, audio: jax.Array,
     the extended tail (``hop_sa_noise_fields(n_hops=...)``).  Returns
     (per-hop logits [(B, C)] * n_hops, new state)."""
     hwp, packed = kws.as_hw_params(hw)
-    x = jnp.concatenate([state.audio_carry, audio], axis=1)
-    new_audio_carry = _tail(x, geom.layers[0].carry)
-    h = kws.hw_conv_layer(hwp, 0, x[..., None], cfg)
+    with jax.named_scope("conv0"):
+        x = jnp.concatenate([state.audio_carry, audio], axis=1)
+        new_audio_carry = _tail(x, geom.layers[0].carry)
+        h = kws.hw_conv_layer(hwp, 0, x[..., None], cfg)
     noise_all = None
     if sa_noise_std > 0.0:
         noise_all = hop_sa_noise_fields(state.key, state.hop, cfg, geom,
@@ -422,27 +426,31 @@ def _stream_advance(hw, state: StreamState, audio: jax.Array,
     for i in range(1, cfg.num_conv_layers):
         lg = geom.layers[i]
         name = f"conv{i}"
-        inp = jnp.concatenate([state.carries[i - 1], h], axis=1)
-        new_carries.append(_tail(inp, lg.carry))
-        noise = noise_all[name] if noise_all is not None else None
-        if bias_delta is not None:
-            t_conv_tail = (inp.shape[1] - cfg.kernels[i]) // cfg.strides[i] + 1
-            noise = _merge_bias_delta(noise, bias_delta[name], t_conv_tail)
-        off = chip_offsets[name] if chip_offsets is not None else None
-        if use_kernel:
-            from repro.kernels.imc_mav import ops as mav_ops
-            h = mav_ops.fused_conv_mav_step(
-                inp, hwp.w_bin[name], hwp.bias[name], hwp.flip[name],
-                groups=cfg.groups(i), stride=cfg.strides[i],
-                pool=cfg.pools[i], chip_offset=off, sa_noise=noise,
-                packed=packed[name] if packed else None)
-        else:
-            h = kws.hw_conv_layer(hwp, i, inp, cfg, chip_offset=off,
-                                  sa_noise=noise, use_kernel=False)
+        with jax.named_scope(name):
+            inp = jnp.concatenate([state.carries[i - 1], h], axis=1)
+            new_carries.append(_tail(inp, lg.carry))
+            noise = noise_all[name] if noise_all is not None else None
+            if bias_delta is not None:
+                t_conv_tail = ((inp.shape[1] - cfg.kernels[i])
+                               // cfg.strides[i] + 1)
+                noise = _merge_bias_delta(noise, bias_delta[name],
+                                          t_conv_tail)
+            off = chip_offsets[name] if chip_offsets is not None else None
+            if use_kernel:
+                from repro.kernels.imc_mav import ops as mav_ops
+                h = mav_ops.fused_conv_mav_step(
+                    inp, hwp.w_bin[name], hwp.bias[name], hwp.flip[name],
+                    groups=cfg.groups(i), stride=cfg.strides[i],
+                    pool=cfg.pools[i], chip_offset=off, sa_noise=noise,
+                    packed=packed[name] if packed else None)
+            else:
+                h = kws.hw_conv_layer(hwp, i, inp, cfg, chip_offset=off,
+                                      sa_noise=noise, use_kernel=False)
     logits_hops = []
     for j in range(1, n_hops + 1):
-        ring = jnp.concatenate([state.ring, h[:, :j * geom.d_feat]],
-                               axis=1)[:, -geom.t_feat:]
+        with jax.named_scope("gap"):
+            ring = jnp.concatenate([state.ring, h[:, :j * geom.d_feat]],
+                                   axis=1)[:, -geom.t_feat:]
         logits_hops.append(_ring_logits(hwp, ring, head_w, head_b))
     new_state = StreamState(audio_carry=new_audio_carry,
                             carries=tuple(new_carries), ring=ring,
@@ -629,6 +637,7 @@ def retention_fills(hw, cfg: kws.KWSConfig, *, key: jax.Array,
     return tuple(fills)
 
 
+@jax.named_scope("fill")
 def gated_step(state: StreamState, cfg: kws.KWSConfig, geom: StreamGeometry,
                fills: Tuple[jax.Array, ...]) -> StreamState:
     """Advance a batch of streams by one *silent* hop without computing.

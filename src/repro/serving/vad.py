@@ -90,6 +90,7 @@ def frame_energy_db(audio: jax.Array) -> jax.Array:
     return 10.0 * jnp.log10(jnp.mean(jnp.square(audio), axis=-1) + _EPS)
 
 
+@jax.named_scope("vad")
 def vad_step(vcfg: VADConfig, state: VADState, audio: jax.Array,
              active: Optional[jax.Array] = None
              ) -> Tuple[VADState, jax.Array]:

@@ -10,11 +10,13 @@ counters.  These comparison loops used to be copy-pasted per test file;
 they live here so the compiled fast path (``repro.serving.compiled``) is
 proven against the exact same notion of "equal" as every older claim.
 
-Counter comparison excludes exactly two registry names
+Counter comparison excludes exactly three registry names
 (:data:`COUNTER_EXCLUDES`): wall-clock hop timing, which is real time and
-can never be equal, and the ``serving.compiled`` block/tick counters,
-which are the one deliberate observable difference between a compiled and
-an interpreted run.  Everything else — hops, gated hops, decisions,
+can never be equal; the ``serving.compiled`` block/tick counters, which
+are the one deliberate observable difference between a compiled and an
+interpreted run; and ``serving.compiles``, which counts the process's jit
+traces and compiles (each server builds its own programs, and whichever
+runs first pays for shared ones).  Everything else — hops, gated hops, decisions,
 admissions, sheds, retires, latency histograms — must match cell for cell.
 """
 
@@ -30,8 +32,10 @@ __all__ = [
 ]
 
 # registry names excluded from counter equality: wall time is physical,
-# and serving.compiled counts blocks/ticks only the compiled server has
-COUNTER_EXCLUDES = ("serving.hop_wall_s", "serving.compiled")
+# serving.compiled counts blocks/ticks only the compiled server has, and
+# serving.compiles counts process-wide jit work
+COUNTER_EXCLUDES = ("serving.hop_wall_s", "serving.compiled",
+                    "serving.compiles")
 
 
 # ---------------------------------------------------------------------------

@@ -8,7 +8,13 @@ what Mosaic cannot tile and kernels that overflow VMEM.  Covered:
 * ``fused_conv_mav`` for every IMC layer (conv1..conv5 of ``KWSConfig()``)
   at the full 16000-sample window with B = 1 and B = 4 slots, and at the
   hop-64 streaming tail with B = 4, clean and with an SA-noise operand;
-* ``sga_update_rows`` (the batched customization update) at B = 1, 2, 4.
+* ``sga_update_rows`` (the batched customization update) at B = 1, 2, 4;
+* one served hop (``stream_step`` + ``decision_step``, SA noise on, B = 4)
+  with the kernels compiled: every Mosaic kernel instruction is named
+  ``%imc_fused.<n>`` (the prefix the benchmark's device-trace reduction
+  matches), and the layer scopes (``conv0``..``conv5``, ``gap``, ``head``,
+  ``decision``) and the VAD's and the gated fill's scopes reach the HLO
+  metadata, which is where a device trace names each op's layer.
 
 The topology is described inside a module fixture, never at import, so
 every pytest-xdist worker collects the same tests; the tests skip only
@@ -16,6 +22,7 @@ where no v5e topology can be described.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +33,10 @@ from repro.core import imc
 from repro.kernels.imc_mav import ops as mav_ops
 from repro.kernels.sga_update.sga_update import sga_update_rows
 from repro.models import kws as m
+from repro.serving import decision as dec
 from repro.serving import make_stream_geometry
+from repro.serving import stream as sv
+from repro.serving import vad as vd
 
 CFG = m.KWSConfig()
 HOP = 64
@@ -120,3 +130,39 @@ def test_sga_update_rows_compiles_for_v5e(one_chip, b):
                                                 interpret=False)
     ).lower(s((b, n)), s((b, n)), s((b, n)), s((b,)), s((b,))).compile()
     _assert_one_kernel(compiled)
+
+
+def test_served_hop_kernel_names_and_scopes_for_v5e(one_chip, monkeypatch):
+    # the served path resolves interpret mode from the default backend
+    # (the CPU here): compile the kernels as on the chip
+    monkeypatch.setattr(mav_ops, "default_interpret", lambda: False)
+    b = 4
+    geom = make_stream_geometry(CFG, HOP)
+    hw = m.fold_params(m.init_params(jax.random.PRNGKey(0), CFG),
+                       m.init_state(CFG), CFG, pack=True)
+    fills = tuple(jnp.zeros((c,), jnp.float32) for c in CFG.channels)
+    dcfg = dec.DecisionConfig()
+    vcfg = vd.VADConfig()
+
+    def hop(hw, st, ds, vs, audio):
+        vs, speech = vd.vad_step(vcfg, vs, audio)
+        lg, new = sv.stream_step(hw, st, audio, CFG, geom, sa_noise_std=1.0)
+        ds, out = dec.decision_step(dcfg, ds, lg, speech)
+        return sv.gated_step(new, CFG, geom, fills), ds, vs, out.score
+
+    args = (hw, sv.zeros_state(CFG, geom, b),
+            dec.decision_init(b, CFG.num_classes), vd.vad_init(b),
+            jnp.zeros((b, HOP), jnp.float32))
+    args = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x),
+                                       sharding=one_chip), args)
+    hlo = jax.jit(hop).lower(*args).compile().as_text()
+    kernels = [line.split("=")[0].strip() for line in hlo.split("\n")
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == CFG.num_conv_layers - 1
+    assert all(k.startswith("%imc_fused") for k in kernels), kernels
+    scopes = {seg for name in re.findall(r'op_name="([^"]*)"', hlo)
+              for seg in name.split("/")}
+    want = {f"conv{i}" for i in range(CFG.num_conv_layers)}
+    want |= {"gap", "head", "decision", "vad", "fill"}
+    assert want <= scopes, sorted(want - scopes)

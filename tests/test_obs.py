@@ -9,15 +9,19 @@
   records, raise throws), a gate region that traces kernels, and a
   per-call over-trace — and reports ZERO violations on real gated /
   faulted / canary / learning traffic in raise mode;
-* telemetry fully on (registry + recorder + auditor raise + trace) is
-  bit-identical to telemetry off — SA noise, chip offsets and fault
-  models included;
+* telemetry fully on (registry + recorder + auditor raise, under an
+  active profiler session) is bit-identical to telemetry off — SA noise,
+  chip offsets and fault models included;
 * ``StreamServer.snapshot()`` v2 round-trips the registry and recorder,
   and the restored server's subsequent events are bit-identical;
-* the Chrome/Perfetto export and the Prometheus text render are
-  well-formed.
+* the profiler trace holds the ``serving.*`` spans of the interpreted
+  tick, and the Prometheus text render is well-formed.
+
+The compiled block's spans, the profiled compiled path and the
+``serving.compiles`` counter are covered in ``tests/test_spans.py``.
 """
 
+import dataclasses
 import json
 import re
 
@@ -26,13 +30,13 @@ import numpy as np
 import pytest
 
 import _equiv as eq
+from _spans import host_spans, inside
 
 from repro.core import faults as flt
 from repro.core import imc
 from repro.models import kws as m
 from repro.obs import (FlightRecorder, LaunchAuditError, LaunchAuditor,
-                       MetricsRegistry, ObsConfig, TraceBuilder,
-                       counter_property)
+                       MetricsRegistry, ObsConfig, counter_property)
 from repro.serving import HealthConfig, StreamServer, VADConfig
 
 L, HOP = 640, 64
@@ -65,7 +69,7 @@ def _gated_wav(rng, n_hops=12, quiet=(4, 9)):
 _VAD = VADConfig(threshold_on_db=-40.0, threshold_off_db=-50.0,
                  wake_margin=1, hang=0)
 
-_OBS_ON = ObsConfig(recorder=64, audit="raise", trace=True)
+_OBS_ON = ObsConfig(recorder=64, audit="raise")
 
 
 # ---------------------------------------------------------------------------
@@ -240,18 +244,17 @@ def test_recorder_snapshot_roundtrip_and_dump(tmp_path):
 
 
 def test_obsconfig_validation_and_env(monkeypatch):
-    assert ObsConfig() == ObsConfig(recorder=0, audit="off", trace=False)
+    assert ObsConfig() == ObsConfig(recorder=0, audit="off")
     with pytest.raises(ValueError):
         ObsConfig(audit="bogus")
     with pytest.raises(ValueError):
         ObsConfig(recorder=-1)
     monkeypatch.setenv("REPRO_OBS_RECORDER", "32")
     monkeypatch.setenv("REPRO_OBS_AUDIT", "raise")
-    monkeypatch.setenv("REPRO_OBS_TRACE", "1")
-    assert ObsConfig.from_env() == ObsConfig(recorder=32, audit="raise",
-                                             trace=True)
-    monkeypatch.setenv("REPRO_OBS_TRACE", "0")
-    assert not ObsConfig.from_env().trace
+    assert ObsConfig.from_env() == ObsConfig(recorder=32, audit="raise")
+    # spans are always on: there is no knob for them
+    assert [f.name for f in dataclasses.fields(ObsConfig)] == [
+        "recorder", "audit"]
 
 
 # ---------------------------------------------------------------------------
@@ -377,15 +380,16 @@ def _run(folded, obs, wavs, **kw):
 
 
 @pytest.mark.streaming
-def test_telemetry_bitexact_gated_noise_offsets(folded):
-    """Telemetry fully on — registry + recorder + auditor in raise mode +
-    trace spans — must not change a single decision on the gated
-    SA-noise + chip-offset configuration."""
+def test_telemetry_bitexact_gated_noise_offsets(folded, tmp_path):
+    """Telemetry fully on — registry + recorder + auditor in raise mode,
+    served under an active profiler session — must not change a single
+    decision on the gated SA-noise + chip-offset configuration."""
     rng = np.random.default_rng(7)
     wavs = {f"s{i}": _gated_wav(rng) for i in range(2)}
     kw = dict(sa_noise_std=0.9, chip_offsets=_chip())
     _, ev_off = _run(folded, ObsConfig(), wavs, **kw)
-    srv, ev_on = _run(folded, _OBS_ON, wavs, **kw)
+    with jax.profiler.trace(str(tmp_path)):
+        srv, ev_on = _run(folded, _OBS_ON, wavs, **kw)
     assert ev_on == ev_off
     assert len(ev_off) > 0
     s = srv.auditor.stats()
@@ -394,7 +398,7 @@ def test_telemetry_bitexact_gated_noise_offsets(folded):
     assert s["calls"]["gate"] > 0                # silence actually gated
     assert s["calls"]["replay"] > 0              # wake replay ran audited
     assert len(srv.recorder.events("tick")) > 0
-    assert len(srv.trace) > 0
+    assert host_spans(tmp_path)["serving.hop"]
 
 
 @pytest.mark.streaming
@@ -498,34 +502,32 @@ def test_snapshot_v2_roundtrips_registry_and_recorder(folded, tmp_path):
     assert ev1 == ev2
 
     def deterministic(reg):
-        # wall-clock counters legitimately differ between processes
+        # wall-clock counters legitimately differ between processes, and
+        # the restored server compiles its own programs
         return [c for c in reg.snapshot()["cells"]
-                if "wall" not in c[0]]
+                if "wall" not in c[0] and c[0] != "serving.compiles"]
 
     assert deterministic(srv2.metrics) == deterministic(srv.metrics)
 
 
 @pytest.mark.streaming
 def test_trace_export_and_prometheus_render(folded, tmp_path):
+    """A profiled interpreted run: every tick is one ``serving.step``
+    holding its phases (admit / hop / gate / decide / riders) on the
+    profiler's clock, and the Perfetto export that replaces the old
+    span dump is written; then the Prometheus render."""
     rng = np.random.default_rng(12)
-    srv, _ = _run(folded, _OBS_ON, {"s0": _gated_wav(rng)})
-    doc = srv.trace.to_chrome()
-    assert doc["displayTimeUnit"] == "ms"
-    evs = doc["traceEvents"]
-    assert evs[0] == {"name": "process_name", "ph": "M", "pid": 0,
-                      "args": {"name": "repro.serving"}}
-    names = {e["name"] for e in evs[1:]}
-    assert {"tick", "hop", "gate", "riders"} <= names
-    for e in evs[1:]:
-        assert e["ph"] == "X"
-        assert e["ts"] >= 0 and e["dur"] >= 0
-        assert "tick" in e["args"]
-    ticks = [e for e in evs[1:] if e["name"] == "tick"]
-    assert all("uj" in e["args"] for e in ticks)
-    path = tmp_path / "trace.json"
-    n = srv.trace.dump(path)
-    assert n == len(srv.trace)
-    assert json.loads(path.read_text())["traceEvents"][0]["ph"] == "M"
+    with jax.profiler.trace(str(tmp_path), create_perfetto_trace=True):
+        srv, _ = _run(folded, _OBS_ON, {"s0": _gated_wav(rng)})
+    spans = host_spans(tmp_path)
+    steps = spans["serving.step"]
+    assert len(steps) == srv._steps
+    assert all(args["ticks"] == 1 for _s, _e, args in steps)
+    for name in ("admit", "hop", "gate", "decide", "riders"):
+        inner = spans[f"serving.{name}"]
+        assert inner, name
+        assert all(inside(sp, steps) for sp in inner), name
+    assert list(tmp_path.rglob("*.trace.json.gz"))
     text = srv.metrics.prometheus_text()
     assert 'serving_batched_calls{cause="hop"}' in text
     assert "serving_tick_uj_count" in text
@@ -583,15 +585,3 @@ def test_sharded_per_device_one_launch_audit(folded, monkeypatch):
     learn = sum(p.stats()["learn_hops"] for p in sh.pools)
     assert learn > 0                         # learning rode the batches
 
-
-def test_trace_builder_relative_timestamps():
-    tb = TraceBuilder(process_name="p")
-    tb.span("a", 10.0, 10.5, tick=0)
-    tb.span("b", 11.0, 11.25, tick=1)
-    tb.counter("c", 11.5, depth=3)
-    tb.instant("i", 12.0)
-    evs = tb.to_chrome()["traceEvents"][1:]
-    assert evs[0]["ts"] == 0.0 and evs[0]["dur"] == 5e5
-    assert evs[1]["ts"] == 1e6 and evs[1]["dur"] == 2.5e5
-    assert evs[2]["ph"] == "C" and evs[2]["args"] == {"depth": 3}
-    assert evs[3]["ph"] == "i" and evs[3]["ts"] == 2e6

@@ -276,7 +276,7 @@ def test_events_device_tags_and_fleet_rollup(folded):
     """Every decision event names the device that produced it (matching
     the router's placement), and the fleet stats rollup equals the sum
     of the per-device pools with zero audit violations."""
-    obs = ObsConfig(recorder=32, audit="raise", trace=False)
+    obs = ObsConfig(recorder=32, audit="raise")
     sh = ShardedStreamServer(folded, CFG, devices=eq.pool_devices(2),
                              slots=2, hop=HOP,
                              seed=0, obs=obs)
