@@ -321,18 +321,37 @@ class CompiledTick:
                     recs[s].wall_s += dt * cnt / len(comp_tick)
 
             # host replay of the per-tick bookkeeping, in tick order — the
-            # exact side-effect sequence of k Python ticks
+            # exact side-effect sequence of k Python ticks, booked in bulk:
+            # each slot's consumed chunks join its ``recent`` window in one
+            # concatenate per block (per-slot hop order is kept), registry
+            # counters move once per tick, and the outputs are read from
+            # Python lists (the values of int/float/bool on the scalars)
+            if trig is not None:
+                trig, kwd, sc = trig.tolist(), kwd.tolist(), sc.tolist()
+            ready_l, speech_l = ready.tolist(), speech.tolist()
+            order = sorted(recs)
+            fresh: Dict[int, list] = {s: [] for s in order}  # unbooked chunks
+
+            def book_recent():
+                for s, chunks in fresh.items():
+                    if chunks:
+                        rec = recs[s]
+                        rec.recent = np.concatenate(
+                            [rec.recent, *chunks])[-window:]
+                        chunks.clear()
+
             events_all: List[dict] = []
             for t in range(k):
                 tick = tick0 + t
                 self._sim_autoscale()
                 tk = sched[t]
                 tick_events: List[dict] = []
-                for s in sorted(recs):
-                    if not ready[t, s]:
+                aged_n = 0
+                for s in order:
+                    if not ready_l[t][s]:
                         continue
                     rec = recs[s]
-                    if speech[t, s]:
+                    if speech_l[t][s]:
                         rec.silent_run = 0
                         if rec.pending:
                             rec.pending = []   # drained by the wake replay
@@ -340,48 +359,35 @@ class CompiledTick:
                         rec.silent_run += 1
                         rec.pending.append(audio[t, s])
                         if len(rec.pending) > m:
-                            aged = rec.pending.pop(0)
-                            rec.recent = np.concatenate(
-                                [rec.recent, aged])[-window:]
+                            fresh[s].append(rec.pending.pop(0))
                             rec.consumed += hop
                             rec.gated_hops += 1
-                            srv._gated_hops += 1
-                for s, js in tk["replays"]:
+                            aged_n += 1
+                # a registry move is a get and a set: skip the zero ones
+                if aged_n:
+                    srv._gated_hops += aged_n
+                # replays first, then regular hops: the Python tick's order
+                hops_t = [(s, j) for s, js in tk["replays"] for j in js]
+                hops_t += tk["regular"]
+                for s, j in hops_t:
                     rec = recs[s]
-                    srv._replay_calls += 1
-                    for j in js:
-                        srv._decisions += 1
-                        srv._speech_hops += 1
-                        rec.recent = np.concatenate(
-                            [rec.recent, seq[s][j]])[-window:]
-                        rec.consumed += hop
-                        rec.hops += 1
-                        ev = {"stream": rec.stream_id, "hop": rec.hops - 1,
-                              "keyword": int(kwd[j, s]),
-                              "score": float(sc[j, s]),
-                              "trigger": bool(trig[j, s])}
-                        tick_events.append(ev)
-                        if ev["trigger"]:
-                            rec.triggers.append(ev)
+                    fresh[s].append(seq[s][j])
+                    rec.consumed += hop
+                    rec.hops += 1
+                    ev = {"stream": rec.stream_id, "hop": rec.hops - 1,
+                          "keyword": kwd[j][s], "score": sc[j][s],
+                          "trigger": trig[j][s]}
+                    tick_events.append(ev)
+                    if ev["trigger"]:
+                        rec.triggers.append(ev)
+                computed = len(hops_t)
+                if computed:
+                    srv._speech_hops += computed
+                    srv._decisions += computed
+                if tk["replays"]:
+                    srv._replay_calls += len(tk["replays"])
                 if tk["regular"]:
                     srv._hop_calls += 1
-                    for s, j in tk["regular"]:
-                        rec = recs[s]
-                        srv._speech_hops += 1
-                        rec.hops += 1
-                        rec.consumed += hop
-                        rec.recent = np.concatenate(
-                            [rec.recent, seq[s][j]])[-window:]
-                    srv._decisions += len(tk["regular"])
-                    for s, j in tk["regular"]:
-                        rec = recs[s]
-                        ev = {"stream": rec.stream_id, "hop": rec.hops - 1,
-                              "keyword": int(kwd[j, s]),
-                              "score": float(sc[j, s]),
-                              "trigger": bool(trig[j, s])}
-                        tick_events.append(ev)
-                        if ev["trigger"]:
-                            rec.triggers.append(ev)
                 if tk["fills"]:
                     srv._gate_calls += 1
 
@@ -400,13 +406,13 @@ class CompiledTick:
                 srv._steps += 1
                 silent_t = (bool(ready[t].any())
                             and not bool((speech[t] & ready[t]).any()))
+                if srv.hcfg is not None:
+                    book_recent()   # a retarget re-inits from ``recent``
                 srv._retarget_hop(tick_events, woke=bool(tk["replays"]),
                                   silent=silent_t)
                 if srv.hcfg is not None and t < k - 1:
                     assert srv._mult == mult0, \
                         "hop retarget fired inside a compiled block"
-                n_replay_hops = sum(len(js) for _, js in tk["replays"])
-                computed = n_replay_hops + len(tk["regular"])
                 gated_n = len(tk["fills"])
                 if srv._rec is not None and (computed or gated_n
                                              or tick_events):
@@ -417,6 +423,7 @@ class CompiledTick:
                                     uj=round(uj, 4))
                     srv._metrics.observe("serving.tick_uj", uj)
                 events_all.extend(tick_events)
+            book_recent()
 
             if srv._audit is not None:
                 srv._audit.end_tick()

@@ -128,14 +128,53 @@ CASES = {
 }
 
 
+_PAIRS = {}
+
+
+def _case_pair(folded, case):
+    """The ``_run_pair`` of one ``CASES`` entry, run once and shared by
+    the tests that read it (each run is seconds of interpret-mode
+    serving)."""
+    if case not in _PAIRS:
+        _PAIRS[case] = _run_pair(folded, CASES[case](), ticks=30)
+    return _PAIRS[case]
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_compiled_block_bitident(folded, case):
     """One fused dispatch for a block of steady-state ticks equals the
     interpreted ticks bit for bit — events, carries, counters."""
-    _, cand, events = _run_pair(folded, CASES[case](), ticks=30)
+    _, cand, events = _case_pair(folded, case)
     assert events                           # the case actually decided
     assert cand._compiled_ticks > 0, "fast path never engaged"
     assert cand._compiled_blocks <= cand._compiled_ticks
+
+
+HOST_FIELDS = ("consumed", "hops", "gated_hops", "silent_run", "triggers")
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_compiled_block_host_fields(folded, case):
+    """The block books every stream's host fields as the interpreted
+    ticks do: the ``recent`` window a hop retarget re-inits from (bit
+    for bit), the hop counts, the deferred hops and the triggers.  In
+    ``dynamic_hop`` the widen lands at the end of a compiled block, so
+    the re-init reads the window the block has just booked."""
+    ref, cand, _ = _case_pair(folded, case)
+    assert ref._streams.keys() == cand._streams.keys()
+    for sid, a in ref._streams.items():
+        b = cand._streams[sid]
+        assert a.recent.dtype == b.recent.dtype, sid
+        np.testing.assert_array_equal(a.recent, b.recent, err_msg=sid)
+        assert a.recent.tobytes() == b.recent.tobytes(), sid
+        for f in HOST_FIELDS:
+            assert getattr(a, f) == getattr(b, f), f"{sid}: {f}"
+        assert len(a.pending) == len(b.pending), sid
+        for x, y in zip(a.pending, b.pending):
+            np.testing.assert_array_equal(x, y, err_msg=f"{sid} pending")
+    if case in ("dynamic_hop", "dynhop_duty_aware"):
+        assert cand._hop_retargets > 0
+        assert cand._hop_retargets == ref._hop_retargets
 
 
 def test_compiled_injected_faults_bitident(folded):
