@@ -1,8 +1,9 @@
 """The comparison that decides ``correct``.
 
 For a sample of streams drawn from the seed, every decision the server
-emitted (keyword, smoothed score) is compared with the plain reference
-(``bench.reference``) run over the same audio:
+emitted (keyword, smoothed score) is compared with the plain reference of
+the configuration's model family (its ``stream_reference``) run over the
+same audio:
 
 * ``decision_count_mismatch``: decisions emitted versus the reference's
   computed hops, summed over the sampled streams (exact, limit 0);

@@ -28,15 +28,16 @@ def control_numbers(spec, cell: dict, seed: int, hops: int,
     import jax
     import jax.numpy as jnp
 
-    from bench import check, reference, serve, traffic as tr, weights
+    from bench import check, serve, traffic as tr
 
+    family = spec.family(cell["config"])
     config = spec.config(cell["config"])
     traffic = spec.traffic(cell["traffic"])
     model, hop = config["model"], config["serving"]["hop"]
     window = model["sample_len"]
     n = streams or traffic["streams"]
     vad = traffic.get("vad")
-    w = weights.draw(model, config["silicon"], seed)
+    w = family.draw(config, seed)
     plan = tr.audio_plan(traffic, n, seed, hop)
     base_key = jax.random.PRNGKey(serve.server_seed(seed))
     taken = len(plan["warmup"]) // hop + hops
@@ -44,12 +45,10 @@ def control_numbers(spec, cell: dict, seed: int, hops: int,
     for i in check.sample_streams(n, traffic["check_streams"], seed):
         audio = tr.full_stream(plan, i, window, taken, hop)
         key = jax.random.fold_in(base_key, i)
-        args = (model, w, audio, taken, hop, key,
-                float(config["silicon"]["sa_noise_std"]), vad,
-                config["decision"]["smooth"])
+        args = (config, w, audio, taken, hop, key, vad)
         sid = f"mic{i}"
-        ref32[sid] = reference.stream_reference(*args)
-        low = reference.stream_reference(*args, dtype=jnp.bfloat16)
+        ref32[sid] = family.stream_reference(*args)
+        low = family.stream_reference(*args, dtype=jnp.bfloat16)
         ctl[sid] = list(zip(low["keyword"].tolist(), low["score"].tolist()))
     return check.compare(ctl, ref32)
 

@@ -23,7 +23,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from bench import reference as ref
+from .reference import groups
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2))
@@ -50,13 +50,15 @@ def _draw(key, shapes: tuple, offset_std: float):
     return w, bias, flip, offsets, fc_w, fc_b
 
 
-def draw(model: dict, silicon: dict, seed: int) -> dict:
-    """The folded weights of ``model`` for ``seed`` (device arrays)."""
+def draw(config: dict, seed: int) -> dict:
+    """The folded weights of the configuration's ``model`` on its
+    ``silicon`` for ``seed`` (device arrays)."""
+    model, silicon = config["model"], config["silicon"]
     chans = model["channels"]
     shapes = []
     for i, k in enumerate(model["kernels"]):
         cin = 1 if i == 0 else chans[i - 1]
-        shapes.append((k, cin // ref.groups(model, i), chans[i]))
+        shapes.append((k, cin // groups(model, i), chans[i]))
     shapes.append((chans[-1], model["num_classes"]))
     key = jax.random.fold_in(jax.random.PRNGKey(0), seed % (2 ** 31))
     key = jax.random.fold_in(key, seed // (2 ** 31))

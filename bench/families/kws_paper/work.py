@@ -10,18 +10,18 @@ computed from these can only err low.
 
 from __future__ import annotations
 
-from bench import reference as ref
+from .reference import geometry, groups
 
 
 def imc_layers(model: dict, hop: int) -> list:
     """Per IMC layer (conv1..): MACs and bytes moved per stream-hop, and
     the weight bytes one call reads."""
-    geo = ref.geometry(model, hop)
+    geo = geometry(model, hop)
     out = []
     for i in range(1, len(model["channels"])):
         c_in, c_out = model["channels"][i - 1], model["channels"][i]
         k = model["kernels"][i]
-        fan_in = k * c_in // ref.groups(model, i)
+        fan_in = k * c_in // groups(model, i)
         n_new = geo[i]["d_out"] * model["pools"][i]   # conv columns a hop
         d_in = geo[i - 1]["d_out"]                    # input columns a hop
         out.append({"name": f"conv{i}",
@@ -34,7 +34,7 @@ def imc_layers(model: dict, hop: int) -> list:
 def hop_flops(model: dict, hop: int) -> int:
     """FLOPs (2 per MAC) of one stream-hop through the whole net: the
     digital sinc layer, the five IMC layers and the FC head."""
-    geo = ref.geometry(model, hop)
+    geo = geometry(model, hop)
     macs = geo[0]["d_out"] * model["channels"][0] * model["kernels"][0]
     macs += sum(l["macs"] for l in imc_layers(model, hop))
     macs += model["channels"][-1] * model["num_classes"]

@@ -1,9 +1,10 @@
 """The fused IMC layer kernels' share of their roofline: the least time the
 chip could take for the stream-hops they computed in the traced window
-(bench.work, bytes or FLOPs, whichever bounds), over their summed device
-time in the trace.  Nothing to read when no IMC kernel ran."""
+(``work.py`` of the ``kws_paper`` family, bytes or FLOPs, whichever
+bounds), over their summed device time in the trace.  Nothing to read
+when no IMC kernel ran."""
 
-from bench import work
+from bench.families.kws_paper import work
 
 
 def read(ctx):

@@ -1,8 +1,9 @@
 """The whole served step's share of the chip's bf16 peak: stream-hops
 computed in the traced window times the FLOPs of one stream-hop through
-the whole net (bench.work), over the window and the peak."""
+the whole net (``work.py`` of the ``kws_paper`` family), over the window
+and the peak."""
 
-from bench import work
+from bench.families.kws_paper import work
 
 
 def read(ctx):

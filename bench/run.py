@@ -5,26 +5,33 @@
 
 The cell (``BENCHMARK.json`` ``workloads``) names a configuration
 (``bench/configs/<config>.json``) and a traffic mix
-(``bench/traffic/<traffic>.json``).  A run:
+(``bench/traffic/<traffic>.json``).  The configuration names its model
+family (``family``: ``bench/families/<family>/``), and the run calls the
+family's three entry points alone (``bench/registry.py``); of the
+configuration it reads only what every family shares: ``model.sample_len``
+(the decision window) and ``model.sample_rate``, ``serving.hop`` and
+``serving.block``, and ``check``.  A run:
 
 1. set-up (``setup_s``): finds the TPU (and fails without one), draws the
-   folded weights from the seed on the device, builds the program's
-   ``StreamServer``, admits every stream in one batched init and steps the
-   traffic's warm-up hops so that every program the window runs is
-   compiled;
+   weights from the seed (the family's ``draw``), builds the program's
+   ``StreamServer`` (``build_server``), admits every stream in one batched
+   init and steps the traffic's warm-up hops so that every program the
+   window runs is compiled;
 2. the window: ``--seconds`` of traffic, open loop (``realtime``) or a
    backlog drained by ``step_block`` (``backlog``), timed on the host
    clock.  With ``--trace 1`` the window is at most the traffic's
    ``trace_seconds`` long and runs under the JAX profiler; the run then
    reports the cell's per-layer metrics instead of its end-to-end ones;
 3. the check: the served decisions of a sample of streams against the
-   plain reference (``bench.reference``), after the server is freed.
+   family's plain reference (``stream_reference``), after the server is
+   freed.
 
 The last line of standard output is the result; the numbers compared,
 each beside its limit, are the last lines of standard error.
 ``--streams`` overrides the traffic's stream count (the knee and backlog
 sweeps in ``PERF.md``).  Exits non-zero with no result when JAX finds no
-TPU, or fewer chips than the cell asks for.
+TPU, or fewer chips than the cell asks for, and at once when the
+configuration names no family that exists.
 """
 
 from __future__ import annotations
@@ -100,9 +107,10 @@ def measure(args, spec, cell: dict, t_start: float) -> None:
     import jax
     import numpy as np
 
-    from bench import (check, devtrace, loops, peaks, reference, registry,
-                       serve, traffic as tr, weights)
+    from bench import (check, devtrace, loops, peaks, registry, serve,
+                       traffic as tr)
 
+    family = spec.family(cell["config"])
     device = find_devices(cell["chips"])
     pk = peaks.peaks(device["kind"])
     log(f"device {device}; compile cache {enable_cache()}")
@@ -114,8 +122,8 @@ def measure(args, spec, cell: dict, t_start: float) -> None:
     vad = traffic.get("vad")
     realtime = traffic["loop"] == "realtime"
 
-    w = weights.draw(model, config["silicon"], args.seed)
-    srv = serve.build_server(config, w, n, args.seed, vad)
+    w = family.draw(config, args.seed)
+    srv = family.build_server(config, w, n, args.seed, vad)
     plan = tr.audio_plan(traffic, n, args.seed, hop)
     sids = tr.stream_ids(n)
     feed = loops.Feed(plan, sids, window, hop)
@@ -174,11 +182,9 @@ def measure(args, spec, cell: dict, t_start: float) -> None:
     refs = {}
     for i in keep:
         taken = int(res["taken"][i])
-        refs[sids[i]] = reference.stream_reference(
-            model, w, tr.full_stream(plan, i, window, taken, hop), taken,
-            hop, jax.random.fold_in(base_key, i),
-            float(config["silicon"]["sa_noise_std"]), vad,
-            config["decision"]["smooth"])
+        refs[sids[i]] = family.stream_reference(
+            config, w, tr.full_stream(plan, i, window, taken, hop), taken,
+            hop, jax.random.fold_in(base_key, i), vad)
     numbers = check.compare(served, refs)
     numbers["hop_mirror_mismatch"] = check.mirror_mismatch(
         {sids[i]: int(res["taken"][i]) for i in range(n)}, server_hops,

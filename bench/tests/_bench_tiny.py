@@ -1,6 +1,6 @@
-"""A tiny copy of the benchmark (configuration, traffic, metrics) for CPU
-tests: the paper net's topology at 8/16 channels and a 1024-sample window,
-a handful of streams, on the jnp path."""
+"""A tiny copy of the benchmark (configuration, traffic, metrics, model
+families) for CPU tests: the paper net's topology at 8/16 channels and a
+1024-sample window, a handful of streams, on the jnp path."""
 
 from __future__ import annotations
 
@@ -27,6 +27,8 @@ def make(tmp: pathlib.Path, traffic: str, *, silicon: bool = True,
     (d / "configs").mkdir(parents=True)
     (d / "traffic").mkdir()
     shutil.copytree(BENCH / "metrics", d / "metrics")
+    shutil.copytree(BENCH / "families", d / "families",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     base = json.loads((BENCH / "configs" / (
         "kws-paper-silicon.json" if silicon else "kws-paper-ideal.json"))
         .read_text())
@@ -49,8 +51,9 @@ def make(tmp: pathlib.Path, traffic: str, *, silicon: bool = True,
     return registry.Spec(tmp / "BENCHMARK.json", d)
 
 
-def args(seconds: float = 0.3, trace: int = 0, seed: int = 2 ** 31 + 5):
-    return types.SimpleNamespace(workload="tiny", seed=seed,
+def args(seconds: float = 0.3, trace: int = 0, seed: int = 2 ** 31 + 5,
+         workload: str = "tiny"):
+    return types.SimpleNamespace(workload=workload, seed=seed,
                                  seconds=seconds, trace=trace, streams=None)
 
 
@@ -71,5 +74,6 @@ def run(spec, capsys, monkeypatch, **kw) -> dict:
     from bench import peaks
     monkeypatch.setitem(peaks.PEAKS, jax.devices()[0].device_kind,
                         peaks.PEAKS["TPU v5 lite"])
-    bench_run.measure(args(**kw), spec, spec.cell("tiny"), 0.0)
+    a = args(**kw)
+    bench_run.measure(a, spec, spec.cell(a.workload), 0.0)
     return json.loads(capsys.readouterr().out.strip().splitlines()[-1])

@@ -6,8 +6,8 @@ from __future__ import annotations
 import json
 import pathlib
 
-from bench import reference as ref
-from bench import work
+from bench.families.kws_paper import reference as ref
+from bench.families.kws_paper import work
 
 MODEL = json.loads((pathlib.Path(__file__).resolve().parents[1] / "configs"
                     / "kws-paper-silicon.json").read_text())["model"]
