@@ -158,10 +158,14 @@ def dense_init(key, d_in: int, d_out: int, bias: bool = False,
     return p
 
 
-def dense(p: Dict, x: jax.Array) -> jax.Array:
-    y = x @ p["w"].astype(x.dtype)
+def dense(p: Dict, x: jax.Array, preferred_element_type=None) -> jax.Array:
+    """``x @ w (+ b)`` with the weights cast to ``x``'s dtype; the product
+    (and the bias) in ``preferred_element_type`` if given: bfloat16
+    operands with a float32 result (``repro.models.kwt``)."""
+    y = jnp.matmul(x, p["w"].astype(x.dtype),
+                   preferred_element_type=preferred_element_type)
     if "b" in p:
-        y = y + p["b"].astype(x.dtype)
+        y = y + p["b"].astype(y.dtype)
     return y
 
 

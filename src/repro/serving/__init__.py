@@ -13,6 +13,9 @@ layer with dynamic hop widening and admission control.
                   multi-hop step and the per-stream bias-delta/head
                   riders), the per-absolute-column SA-noise field, the
                   gated (no-IMC) state advance, work accounting
+  kwt_stream.py — the Keyword Transformer's engine (repro.models.kwt):
+                  an MFCC frame ring and the whole encoder every hop;
+                  StreamServer builds it for a KWTConfig
   vad.py        — log-energy EMA + hysteresis voice-activity detector
   decision.py   — posterior smoothing + hysteresis + refractory triggers
   scheduler.py  — StreamServer: slots, admission queue + backpressure,
@@ -60,6 +63,7 @@ from repro.obs import (FlightRecorder, LaunchAuditError, LaunchAuditor,
 from repro.serving.customize import (CustomizationResult,
                                      CustomizationSession, CustomizeConfig)
 from repro.serving.health import HealthConfig, HealthMonitor
+from repro.serving.kwt_stream import KWTEngine, KWTState
 from repro.serving.decision import (DecisionConfig, DecisionOut,
                                     DecisionState, decision_init,
                                     decision_step)
@@ -82,7 +86,8 @@ __all__ = [
     "CustomizationResult", "CustomizationSession",
     "CustomizeConfig", "DecisionConfig", "DecisionOut", "DecisionState",
     "DynamicHopConfig", "FaultConfig", "FaultModel", "FlightRecorder",
-    "HealthConfig", "HealthMonitor", "LaunchAuditError", "LaunchAuditor",
+    "HealthConfig", "HealthMonitor", "KWTEngine", "KWTState",
+    "LaunchAuditError", "LaunchAuditor",
     "MetricsRegistry", "ObsConfig", "SANoiseField", "ShardedStreamServer",
     "StreamServer",
     "StreamEngine", "StreamGeometry", "StreamState",

@@ -13,7 +13,8 @@ block of K ticks is one host->device round trip.
 **What runs inside the scan** (dispatch 2, the main block):
 
 * per scan step, at most one new hop per slot: a ``lax.cond``-gated
-  masked ``stream_step`` (or its per-slot-rider customized variant) +
+  masked step of the server's engine (its ``pure_step``: ``stream_step``,
+  or KWT's frame-ring step; or the per-slot-rider customized variant) +
   ``decision_step`` for the computed slots, then a ``lax.cond``-gated
   masked ``gated_step`` for the slots whose deferred silent hop aged out
   of the wake margin.  Masked rows ride verbatim — exactly the Python
@@ -219,8 +220,7 @@ class CompiledTick:
                                 hw, st, a, cfg, geom, **kw, bias_delta=d,
                                 head_w=hw_, head_b=hb_)
                         else:
-                            lg, new = sv.stream_step(hw, st, a, cfg, geom,
-                                                     **kw)
+                            lg, new = eng.pure_step(st, a)
                         st2 = _select_state(cmj, new, st)
                         ds2, out = dec.decision_step(dcfg, ds, lg, cmj)
                         return st2, ds2, (out.trigger, out.keyword,
@@ -262,6 +262,7 @@ class CompiledTick:
         srv = self._srv
         hop = srv.geom.hop
         window = srv.geom.window
+        frames_per_hop = srv.geom.frames_per_hop if srv._kwt else 0
         n = srv.slots
         m = srv.vcfg.wake_margin if srv.vcfg is not None else 0
         tick0 = srv._steps
@@ -384,6 +385,8 @@ class CompiledTick:
                 if computed:
                     srv._speech_hops += computed
                     srv._decisions += computed
+                    if srv._kwt:
+                        srv._mfcc_frames += computed * frames_per_hop
                 if tk["replays"]:
                     srv._replay_calls += len(tk["replays"])
                 if tk["regular"]:

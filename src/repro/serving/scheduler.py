@@ -103,6 +103,12 @@ written .npz (tmp+fsync+``os.replace``, the ProfileStore idiom);
 ``restore()`` on a freshly constructed identically-configured server
 resumes bit-identically to an uninterrupted run (test-enforced).
 
+**Two networks.**  ``cfg`` picks the engine: a ``KWSConfig`` serves the
+folded IMC net (``repro.serving.stream``), a ``KWTConfig`` the Keyword
+Transformer (``repro.serving.kwt_stream``: an MFCC frame ring and the
+whole encoder per hop).  A KWT server refuses, at construction, every
+option that only the IMC net has (``_KWT_REFUSED``).
+
 Per-hop logits flow into the shared decision head
 (repro.serving.decision): smoothing + hysteresis + refractory, batched and
 mask-aware.  ``stats()`` reports per-stream and aggregate decisions/sec,
@@ -127,10 +133,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import energy
-from repro.models import kws
+from repro.models import kws, kwt
 from repro.obs import (FlightRecorder, LaunchAuditor, MetricsRegistry,
                        ObsConfig, compiles, counter_property, span)
 from repro.serving import decision as dec
+from repro.serving import kwt_stream
 from repro.serving import stream as sv
 from repro.serving import vad as vd
 
@@ -267,6 +274,7 @@ def _snap_class(name: str):
         return HeadState
     return {"StreamState": sv.StreamState,
             "WindowState": sv.WindowState,
+            "KWTState": kwt_stream.KWTState,
             "DecisionState": dec.DecisionState,
             "VADState": vd.VADState}[name]
 
@@ -327,6 +335,29 @@ def _snap_decode(spec: dict, arrays: Dict[str, np.ndarray]):
     raise ValueError(f"unknown snapshot node type {t!r}")
 
 
+_KWT_REFUSED = {
+    "chip_offsets": "chip offsets model the IMC macro",
+    "sa_noise_std": "SA noise models the IMC macro (sa_noise_std > 0)",
+    "use_kernel": "the fused IMC kernel runs the folded net only "
+                  "(use_kernel=False)",
+    "streaming": "the frame ring is KWT's one engine (streaming=False)",
+    "vad": "VAD gating shifts the folded net's silence columns",
+    "dynamic_hop": "a hop retarget re-inits the folded net's geometry",
+    "faults": "fault injection rides the IMC bias-delta riders",
+    "health": "canaries localize IMC layer faults",
+    "profiles": "profiles customize the folded net",
+    "obs": "the launch auditor counts IMC kernel launches and the flight "
+           "recorder charges the IMC energy model",
+}
+
+
+def _refuse_kwt_options(**given) -> None:
+    """``ValueError`` naming every option a KWT server cannot take."""
+    bad = [f"{k} ({_KWT_REFUSED[k]})" for k, on in given.items() if on]
+    if bad:
+        raise ValueError("a KWTConfig server cannot take: " + "; ".join(bad))
+
+
 class StreamServer:
     """Admit / batch / gate / decide / evict over an autoscaling slot pool."""
 
@@ -360,8 +391,12 @@ class StreamServer:
     # and an interpreted run (tests/_equiv.py excludes them)
     _compiled_blocks = counter_property("serving.compiled", what="blocks")
     _compiled_ticks = counter_property("serving.compiled", what="ticks")
+    # MFCC frames computed (KWT only): a window's frames per init, the
+    # hop's fresh frames per computed hop — the frame ring's evidence
+    _mfcc_frames = counter_property("serving.mfcc_frames")
 
-    def __init__(self, hw, cfg: kws.KWSConfig, *, hop: int, slots: int = 4,
+    def __init__(self, hw, cfg: kws.KWSConfig | kwt.KWTConfig, *, hop: int,
+                 slots: int = 4,
                  chip_offsets: Optional[Dict[str, jax.Array]] = None,
                  sa_noise_std: float = 0.0, use_kernel: bool = True,
                  streaming: bool = True,
@@ -380,6 +415,19 @@ class StreamServer:
         # the first counter write below
         self._metrics = MetricsRegistry()
         self.obs = obs if obs is not None else ObsConfig.from_env()
+        # a KWTConfig serves the Keyword Transformer (repro.serving.
+        # kwt_stream): no IMC macro, so every option of the folded net's
+        # silicon, gating and learning is refused rather than ignored
+        self._kwt = isinstance(cfg, kwt.KWTConfig)
+        if self._kwt:
+            _refuse_kwt_options(
+                chip_offsets=chip_offsets is not None,
+                sa_noise_std=sa_noise_std > 0.0, use_kernel=use_kernel,
+                streaming=not streaming, vad=vad is not None,
+                dynamic_hop=dynamic_hop is not None,
+                faults=faults is not None, health=health is not None,
+                profiles=profiles is not None,
+                obs=self.obs.audit != "off" or self.obs.recorder > 0)
         # ``device_label`` names this server's device pool in a sharded
         # deployment (repro.serving.shard): the launch auditor and fleet
         # stats rollups attribute per-device launches through it
@@ -487,6 +535,8 @@ class StreamServer:
         self._gate_calls = 0               # masked no-op fill calls
         self._compiled_blocks = 0
         self._compiled_ticks = 0
+        if self._kwt:
+            self._mfcc_frames = 0
 
         # compiled whole-tick fast path (repro.serving.compiled):
         # ``compiled=True`` (defaults) or a CompiledTickConfig turns
@@ -539,8 +589,13 @@ class StreamServer:
     def _bundle(self, mult: int) -> dict:
         """Engine + jitted masked hop/gate functions for one hop multiple."""
         if mult not in self._mults:
-            eng = sv.StreamEngine(self._hw, self.cfg, self.base_hop * mult,
-                                  **self._engine_kw)
+            if self._kwt:
+                eng = kwt_stream.KWTEngine(self._hw, self.cfg,
+                                           self.base_hop * mult)
+            else:
+                eng = sv.StreamEngine(self._hw, self.cfg,
+                                      self.base_hop * mult,
+                                      **self._engine_kw)
 
             def hop_masked(state, audio, mask, _step=eng._step):
                 logits, new_state = _step(state, audio)
@@ -554,6 +609,12 @@ class StreamServer:
             def init_masked(state, windows, keys, mask, _init=eng._init):
                 logits, new_state = _init(windows, keys)
                 return logits, _select_state(mask, new_state, state)
+
+            if self._kwt:      # no riders, gating or replays to build
+                self._mults[mult] = {"engine": eng,
+                                     "hop": jax.jit(hop_masked),
+                                     "init": jax.jit(init_masked)}
+                return self._mults[mult]
 
             step_fn = sv.stream_step if self.streaming else sv.window_step
             init_fn = sv.stream_init if self.streaming else sv.window_init
@@ -626,11 +687,13 @@ class StreamServer:
         return cache[n_hops]
 
     @property
-    def engine(self) -> sv.StreamEngine:
+    def engine(self):
+        """The ``StreamEngine`` (or, for a KWTConfig, the
+        ``kwt_stream.KWTEngine``) at the current hop."""
         return self._bundle(self._mult)["engine"]
 
     @property
-    def geom(self) -> sv.StreamGeometry:
+    def geom(self):
         return self.engine.geom
 
     @property
@@ -686,6 +749,11 @@ class StreamServer:
     def _base_head(self):
         hwp, _ = kws.as_hw_params(self._hw)
         return hwp.fc_w, hwp.fc_b
+
+    def _refuse_customization(self) -> None:
+        if self._kwt:
+            raise ValueError("a KWTConfig server cannot take customization "
+                             "(it refolds the IMC net's biases and head)")
 
     def _enable_customization(self) -> None:
         """Materialize the per-slot customization arrays (zero bias deltas,
@@ -827,6 +895,7 @@ class StreamServer:
         fine-tune) runs as bounded background jobs inside ``step()``.  See
         repro.serving.customize.  Returns the CustomizationSession."""
         from repro.serving import customize as cz
+        self._refuse_customization()
         if self.hcfg is not None:
             raise ValueError("customization requires a fixed hop "
                              "(dynamic_hop retargets would break the "
@@ -844,6 +913,7 @@ class StreamServer:
         (empty) if it does not exist yet, so a profile can be installed
         before its first audio arrives."""
         from repro.serving import customize as cz
+        self._refuse_customization()
         self._enable_customization()
         rec = self._streams.get(stream_id)
         if rec is None:
@@ -1300,6 +1370,8 @@ class StreamServer:
             dt = time.perf_counter() - t0
             self._hop_wall_s += dt
             self._init_calls += 1
+            if self._kwt:
+                self._mfcc_frames += len(todo) * self.geom.frames
             for s, rec in todo:
                 _book(rec, s, windows[s], dt / len(todo))
                 init_logits[s] = np.asarray(logits[s])
@@ -1324,6 +1396,8 @@ class StreamServer:
             # must count too (decisions_per_sec = decisions / hop_wall_s)
             self._hop_wall_s += dt
             self._init_calls += 1
+            if self._kwt:
+                self._mfcc_frames += self.geom.frames
             _book(rec, s, first, dt)
             init_logits[s] = np.asarray(logits[0])
         return init_mask, init_logits
@@ -1522,6 +1596,8 @@ class StreamServer:
             self._hop_wall_s += dt
             self._hop_calls += 1
             n_active = int(compute_mask.sum())
+            if self._kwt:
+                self._mfcc_frames += n_active * self.geom.frames_per_hop
             for s, rec in enumerate(self._slots):
                 if compute_mask[s]:
                     if rec.internal:
@@ -1837,10 +1913,14 @@ class StreamServer:
         return [r.stream_id for r in self._slots if r is not None]
 
     def stats(self) -> dict:
-        offline = kws.layer_stats(self.cfg)
-        streaming = sv.streaming_layer_stats(self.cfg, self.geom)
-        macs_off = sum(s["macs"] for s in offline)
-        macs_str = sum(s["macs"] for s in streaming)
+        if self._kwt:
+            macs_off = kwt.decision_macs(self.cfg, self.geom.frames)
+            macs_str = kwt.decision_macs(self.cfg, self.geom.frames_per_hop)
+        else:
+            offline = kws.layer_stats(self.cfg)
+            streaming = sv.streaming_layer_stats(self.cfg, self.geom)
+            macs_off = sum(s["macs"] for s in offline)
+            macs_str = sum(s["macs"] for s in streaming)
         per_stream = {
             rec.stream_id: {
                 "hops": rec.hops,

@@ -721,7 +721,10 @@ class StreamEngine:
         step = stream_step if streaming else window_step
         geom = self.geom
         self._init = jax.jit(lambda w, k: init(hw, w, k, cfg, geom, **kw))
-        self._step = jax.jit(lambda s, a: step(hw, s, a, cfg, geom, **kw))
+        # the pure step the compiled block's scan calls (repro.serving.
+        # compiled), and its jitted form
+        self.pure_step = lambda s, a: step(hw, s, a, cfg, geom, **kw)
+        self._step = jax.jit(self.pure_step)
         # customized (per-stream bias delta + head) and multi-hop variants,
         # jitted on first use so the plain serving path never pays for them
         self._init_cust = None
